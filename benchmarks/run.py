@@ -57,7 +57,7 @@ _TABLES = [
      "api: unified query plane (plan lowering + region latency)"),
     ("scale", "benchmarks.bench_scale", "§5: range decode / memory budget"),
     ("sharded", "benchmarks.bench_sharded",
-     "beyond-paper: mesh-partitioned residency vs width (8 host devices)"),
+     "beyond-paper: mesh-partitioned residency vs width (devices present)"),
     ("e2e", "benchmarks.bench_e2e", "§6.1: host-link ceiling"),
     ("ratio", "benchmarks.bench_ratio", "§6.2: ratio + stream separation"),
     ("entropy", "benchmarks.bench_entropy", "§6.4: open entropy stage"),

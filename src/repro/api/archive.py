@@ -330,6 +330,12 @@ class GenomicArchive:
         bytes_resident, decode_launches, policy (zeros when disabled)."""
         return self.store.cache_info()
 
+    def clear_cache(self) -> None:
+        """Drop every decoded block from the cache (its counters keep
+        counting), so the next query starts cold."""
+        if self.store._cache is not None:
+            self.store._cache.reset()
+
     def recover_info(self) -> dict:
         """Recovery counters of the underlying decoder: blocks
         parity-`reconstructed`, decode `retries`, `unrecoverable`

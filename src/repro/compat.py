@@ -1,70 +1,36 @@
-"""Version-compatibility shims for the installed jax.
+"""The repo's one spelling of the jax mesh/sharding calls (jax 0.9).
 
-The codebase targets the current jax API surface (``jax.shard_map``,
-``jax.set_mesh``, ``jax.sharding.AxisType``); older releases (e.g. the
-0.4.x line in this container) expose the same functionality under
-different names. Everything version-sensitive funnels through here so the
-rest of the tree imports one stable spelling:
-
-    make_mesh(shape, axes)   — jax.make_mesh, with Auto axis types when
-                               this jax knows about axis types at all
-    mesh_context(mesh)       — ``jax.set_mesh(mesh)`` or the Mesh context
-                               manager (ambient-mesh install for jit)
+    make_mesh(shape, axes)   — jax.make_mesh with Auto axis types
+    mesh_context(mesh)       — jax.set_mesh(mesh) (ambient mesh for jit)
     shard_map(f, mesh=, in_specs=, out_specs=)
-                             — jax.shard_map(check_vma=False) or
-                               jax.experimental shard_map(check_rep=False)
+                             — jax.shard_map with check_vma=False
+    cost_analysis(compiled)  — compiled.cost_analysis() as a dict
 """
 from __future__ import annotations
 
-import inspect
-
 import jax
-
-try:                                            # jax >= 0.5
-    from jax.sharding import AxisType
-except ImportError:                             # pragma: no cover - version dep
-    AxisType = None
-
-_MAKE_MESH_HAS_AXIS_TYPES = (
-    "axis_types" in inspect.signature(jax.make_mesh).parameters)
+from jax.sharding import AxisType
 
 
 def make_mesh(shape, axes):
-    """``jax.make_mesh`` with Auto axis types when supported.
-
-    Older jax has neither ``AxisType`` nor the ``axis_types`` kwarg; its
-    meshes behave as Auto on every axis, so omitting the argument is the
-    faithful fallback.
-    """
-    if AxisType is not None and _MAKE_MESH_HAS_AXIS_TYPES:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with Auto axis types on every axis."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def mesh_context(mesh):
     """Context manager installing `mesh` as the ambient mesh for jit."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh          # jax.sharding.Mesh is itself a context manager
+    return jax.set_mesh(mesh)
 
 
 def cost_analysis(compiled) -> dict:
-    """`compiled.cost_analysis()` as a flat dict (older jax returns a
-    one-element list of dicts, newer returns the dict directly)."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
+    """`compiled.cost_analysis()` (a flat dict of cost counters)."""
+    return compiled.cost_analysis()
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
     """Per-shard mapping without replication checking (our bodies psum
     explicitly where needed; the decode bodies are embarrassingly
     parallel)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

@@ -29,6 +29,14 @@ from repro.core.format import (FNV_OFFSET, N_STREAMS, S_COMMANDS, S_LENGTHS,
                                file_digest)
 
 
+# Most blocks one whole-archive decode launch takes. Compiled for a TPU
+# v5e, a launch's temp memory grows with it (6.5 GB at 3405 x 64 KiB
+# blocks, 1 GB at 512) and so, unevenly, does its compile time (~100 s
+# at 3405 blocks, ~20 s at 512), so `decode_all` walks an "ra" archive
+# in launches of this many blocks.
+MAX_LAUNCH_BLOCKS = 512
+
+
 class BlockDigestError(ValueError):
     """A decoded block's FNV-1a-64 digest does not match the archive's."""
 
@@ -151,10 +159,20 @@ def _linearize(rows: jnp.ndarray, n: jnp.ndarray, k: jnp.ndarray,
     """rows (B, T*k_max) step-major rANS output → (B, out_len) linear bytes.
 
     Symbol i lives at (i // K) * k_max + (i % K); i >= n → 0.
+
+    The quotient is taken in f32 and corrected by one: the TPU compiler
+    spends over 100 s on an s32 divide of a large iota by a per-row
+    divisor (256 x 64 KiB), and f32 holds every i < 2^24 exactly, so
+    the rounded quotient is off by at most one.
     """
+    assert out_len < 2**24, out_len
     i = jnp.arange(out_len, dtype=jnp.int32)[None, :]
     k = jnp.maximum(k, 1)[:, None]
-    idx = (i // k) * k_max + (i % k)
+    q = jnp.floor(i.astype(jnp.float32) / k.astype(jnp.float32)
+                  ).astype(jnp.int32)
+    r = i - q * k
+    q = jnp.where(r < 0, q - 1, jnp.where(r >= k, q + 1, q))
+    idx = q * k_max + (i - q * k)
     idx = jnp.clip(idx, 0, rows.shape[1] - 1)
     vals = jnp.take_along_axis(rows, idx, axis=1)
     return jnp.where(i < n[:, None], vals, 0).astype(jnp.uint8)
@@ -958,8 +976,10 @@ class Decoder:
     def decode_all(self, chunk_blocks: Optional[int] = None,
                    mode2: bool = True, verify: bool = False,
                    on_error: str = "raise") -> np.ndarray:
-        """Whole-file decode; with chunk_blocks set, never materializes more
-        than one chunk of decompressed output at a time (paper §5 v7-RA).
+        """Whole-file decode; never materializes more than one chunk of
+        decompressed output at a time (paper §5 v7-RA). `chunk_blocks`
+        defaults to `MAX_LAUNCH_BLOCKS` for "ra" archives and to the whole
+        archive for global ones (their decode windows cross blocks).
         Compatibility shim over `StreamingExecutor`.
 
         verify=True additionally checks `file_fnv` over the block digest
@@ -973,6 +993,11 @@ class Decoder:
         raw = self.da.raw_size
         if raw == 0:
             return np.zeros(0, np.uint8)
+        n_blocks = self.da.n_blocks
+        if chunk_blocks is None:
+            chunk_blocks = (min(n_blocks, MAX_LAUNCH_BLOCKS)
+                            if self.da.mode == "ra" else n_blocks)
+        step = int(chunk_blocks)
         if verify:
             a = self.archive
             if file_digest(a.block_fnv) != a.file_fnv:
@@ -982,19 +1007,19 @@ class Decoder:
                     f"{a.file_fnv:#018x}")
             decode = (self.decode_blocks if mode2
                       else self.decode_blocks_host_entropy)
-            step = int(chunk_blocks or self.da.n_blocks)
             parts = []
-            for lo in range(0, self.da.n_blocks, step):
-                sel = np.arange(lo, min(lo + step, self.da.n_blocks))
-                rows = np.asarray(decode(sel, verify=True,
+            for lo in range(0, n_blocks, step):
+                sel = np.arange(lo, min(lo + step, n_blocks))
+                # the last chunk repeats its final block up to `step`
+                # ids, so every launch of the loop shares one shape
+                padded = np.minimum(np.arange(lo, lo + step), n_blocks - 1)
+                rows = np.asarray(decode(padded, verify=True,
                                          on_error=on_error))
                 parts.extend(rows[i, :int(a.block_len[b])]
                              for i, b in enumerate(sel))
             return np.concatenate(parts) if parts else np.zeros(0, np.uint8)
         from repro.api.address import ByteRange
         from repro.api.executors import StreamingExecutor
-        ex = StreamingExecutor(
-            self._api_store(),
-            max_blocks_per_chunk=chunk_blocks or self.da.n_blocks,
-            mode2=mode2)
+        ex = StreamingExecutor(self._api_store(), max_blocks_per_chunk=step,
+                               mode2=mode2)
         return np.concatenate(list(ex.chunks([ByteRange(0, raw)])))

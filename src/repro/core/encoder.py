@@ -7,6 +7,9 @@ over every stream of every block.
 """
 from __future__ import annotations
 
+import multiprocessing
+import os
+from concurrent.futures import ProcessPoolExecutor
 from typing import List
 
 import numpy as np
@@ -35,6 +38,88 @@ def _planes_u64(vals: np.ndarray) -> np.ndarray:
     v = vals.astype(np.uint64)
     return np.concatenate([((v >> np.uint64(8 * b)) & np.uint64(0xFF)).astype(np.uint8)
                            for b in range(8)])
+
+
+# "ra" inputs at least this large encode their blocks in a process pool;
+# below it the pool's start-up (a fresh interpreter per worker) costs
+# more than it saves
+_PARALLEL_MIN_BYTES = 32 << 20
+
+
+def _commands(blk: np.ndarray, tokens) -> tuple:
+    """Greedy-parse tokens → (lit_lens u32, match_lens u32, offsets u64,
+    literals u8) command arrays of one block; literal runs longer than
+    MAX_LEN split into literal-only commands."""
+    lit_lens: List[int] = []
+    mlens: List[int] = []
+    offs: List[int] = []
+    lit_chunks: List[np.ndarray] = []
+    cur = 0
+    for (ll, ml, src) in tokens:
+        if ll:
+            lit_chunks.append(blk[cur:cur + ll])
+        cur += ll + ml
+        while ll > MAX_LEN:
+            lit_lens.append(MAX_LEN)
+            mlens.append(0)
+            offs.append(0)
+            ll -= MAX_LEN
+        lit_lens.append(ll)
+        mlens.append(ml)
+        # "ra": src is already block-local (find_matches base=0);
+        # "global": src is absolute
+        offs.append(src if ml else 0)
+    assert cur == blk.size, f"parse covered {cur} of {blk.size}"
+    literals = (np.concatenate(lit_chunks) if lit_chunks
+                else np.zeros(0, np.uint8))
+    return (np.asarray(lit_lens, np.uint32), np.asarray(mlens, np.uint32),
+            np.asarray(offs, np.uint64), literals)
+
+
+def _ra_block(blk: np.ndarray, hash_bits: int, offset_bytes: int) -> tuple:
+    """One self-contained "ra" block → (fnv, n_cmds, depth, [literals,
+    lengths, offsets, commands] streams). Depends on the block alone, so
+    blocks encode in any order and on any worker."""
+    cand, mlen = ms.find_matches(blk, base=0, hash_bits=hash_bits)
+    ll_a, ml_a, of_a, literals = _commands(
+        blk, ms.greedy_parse(blk.size, cand, mlen))
+    planes = _planes_u16 if offset_bytes == 2 else _planes_u32
+    return (np.uint64(fnv1a64_u64_stride(blk)), ll_a.size,
+            dpth.block_depth_ra(ll_a, ml_a, of_a, blk.size),
+            [literals, _planes_u16(ml_a), planes(of_a), _planes_u16(ll_a)])
+
+
+def _ra_chunk(chunk: bytes, lens: List[int], hash_bits: int,
+              offset_bytes: int) -> list:
+    """Pool task: consecutive "ra" blocks packed in `chunk`."""
+    data = np.frombuffer(chunk, np.uint8)
+    out, pos = [], 0
+    for ln in lens:
+        out.append(_ra_block(data[pos:pos + ln], hash_bits, offset_bytes))
+        pos += ln
+    return out
+
+
+def _ra_blocks(data: np.ndarray, block_len: np.ndarray, hash_bits: int,
+               offset_bytes: int) -> list:
+    """Every "ra" block of `data`, in block order. Large inputs fan out
+    over a `spawn` process pool (one worker per usable core; workers run
+    numpy only, never JAX); the result is the serial result."""
+    lens = block_len.tolist()
+    workers = len(os.sched_getaffinity(0))
+    if data.size < _PARALLEL_MIN_BYTES or workers < 2 or len(lens) < 2:
+        return _ra_chunk(data.tobytes(), lens, hash_bits, offset_bytes)
+    per = max(1, -(-len(lens) // (4 * workers)))
+    starts = np.concatenate([[0], np.cumsum(block_len, dtype=np.int64)])
+    with ProcessPoolExecutor(
+            max_workers=workers,
+            mp_context=multiprocessing.get_context("spawn")) as pool:
+        futs = [pool.submit(_ra_chunk,
+                            data[starts[i]:starts[min(i + per, len(lens))]]
+                            .tobytes(), lens[i:i + per], hash_bits,
+                            offset_bytes)
+                for i in range(0, len(lens), per)]
+        return [blk for f in futs for blk in f.result()]
 
 
 def validate_encode_params(block_size: int, mode: str, entropy: str,
@@ -142,11 +227,7 @@ def encode(data: bytes | np.ndarray,
     # block fits 16 bits. Larger blocks (e.g. PAPER1_BLOCK_SIZE) switch to
     # four planes — storing a >=64 KiB offset in two would silently
     # truncate it and corrupt every match past the 16-bit horizon.
-    if mode == "ra":
-        offset_bytes = 2 if block_size <= 0xFFFF else 4
-        _ra_planes = _planes_u16 if offset_bytes == 2 else _planes_u32
-    else:
-        offset_bytes = 8
+    offset_bytes = (2 if block_size <= 0xFFFF else 4) if mode == "ra" else 8
     n_blocks = max(1, -(-n // block_size))
     block_start = origin + (np.arange(n_blocks, dtype=np.int64) * block_size)
     block_len = np.minimum(n - (block_start - origin),
@@ -175,11 +256,18 @@ def encode(data: bytes | np.ndarray,
                                              hash_bits=hash_bits)
 
     streams: List[np.ndarray] = []
-    class_ids: List[int] = []
     n_cmds = np.zeros(n_blocks, np.int32)
     block_fnv = np.zeros(n_blocks, np.uint64)
     block_depth = np.zeros(n_blocks, np.int32)
-    if mode == "global":
+    if mode == "ra":
+        # every block resolves alone: its exact pointer-resolution depth
+        # is measured with it (the decoder runs exactly that many
+        # doubling rounds instead of ceil(log2(block_size)))
+        for b, (fnv, nc, depth, blk_streams) in enumerate(
+                _ra_blocks(data, block_len, hash_bits, offset_bytes)):
+            block_fnv[b], n_cmds[b], block_depth[b] = fnv, nc, depth
+            streams.extend(blk_streams)
+    else:
         # wavefront chains cross blocks, so depth is measured per anchor
         # window; blocks arrive in order, so one window's pointer arrays
         # (i32, window-relative — windows are guarded < 2^31 bytes) are
@@ -189,64 +277,23 @@ def encode(data: bytes | np.ndarray,
         # guard above already bounds.
         win_of = (np.searchsorted(anchors, np.arange(n_blocks), "right") - 1
                   if anchors.size else np.zeros(n_blocks, np.int64))
-    win_ptrs: List[np.ndarray] = []
-    win_first = 0
-
-    for b in range(n_blocks):
-        s, ln = int(block_start[b]) - origin, int(block_len[b])
-        blk = data[s:s + ln]
-        block_fnv[b] = np.uint64(fnv1a64_u64_stride(blk))
-        if mode == "ra":
-            cand, mlen = ms.find_matches(blk, base=0, hash_bits=hash_bits)
-            tokens = ms.greedy_parse(ln, cand, mlen)
-        else:
+        win_ptrs: List[np.ndarray] = []
+        win_first = 0
+        for b in range(n_blocks):
+            s, ln = int(block_start[b]) - origin, int(block_len[b])
+            blk = data[s:s + ln]
+            block_fnv[b] = np.uint64(fnv1a64_u64_stride(blk))
             # global candidates; cap match dest inside this block
             c = g_cand[s:s + ln].copy()
             m = g_mlen[s:s + ln].copy()
             m = np.minimum(m, ln - np.arange(ln))
             m = np.where(m >= ms.MIN_MATCH, m, 0)
-            tokens = [(ll, ml, src) for (ll, ml, src)
-                      in ms.greedy_parse(ln, np.where(m > 0, c, -1), m)]
-
-        lit_lens: List[int] = []
-        mlens: List[int] = []
-        offs: List[int] = []
-        lit_chunks: List[np.ndarray] = []
-        cur = 0
-        for (ll, ml, src) in tokens:
-            if ll:
-                lit_chunks.append(blk[cur:cur + ll])
-            cur += ll + ml
-            while ll > MAX_LEN:
-                lit_lens.append(MAX_LEN)
-                mlens.append(0)
-                offs.append(0)
-                ll -= MAX_LEN
-            lit_lens.append(ll)
-            mlens.append(ml)
-            if ml:
-                # "ra": src is already block-local (find_matches base=0);
-                # "global": src is absolute
-                offs.append(src)
-            else:
-                offs.append(0)
-        assert cur == ln, f"parse covered {cur} of {ln}"
-        n_cmds[b] = len(lit_lens)
-
-        literals = (np.concatenate(lit_chunks) if lit_chunks
-                    else np.zeros(0, np.uint8))
-        ll_a = np.asarray(lit_lens, np.uint32)
-        ml_a = np.asarray(mlens, np.uint32)
-        of_a = np.asarray(offs, np.uint64)
-        # measure the block's exact pointer-resolution depth: the decoder
-        # will run exactly this many doubling rounds instead of
-        # ceil(log2(block_size)). "ra" blocks resolve alone; global-mode
-        # chains cross blocks, so pointers buffer per anchor window
-        # (rebased to window coordinates — the host twin of the decode's
-        # flat pointer space) and resolve at the window edge.
-        if mode == "ra":
-            block_depth[b] = dpth.block_depth_ra(ll_a, ml_a, of_a, ln)
-        else:
+            ll_a, ml_a, of_a, literals = _commands(
+                blk, ms.greedy_parse(ln, np.where(m > 0, c, -1), m))
+            n_cmds[b] = ll_a.size
+            # pointers buffer per anchor window (rebased to window
+            # coordinates — the host twin of the decode's flat pointer
+            # space) and resolve at the window edge
             if not win_ptrs:
                 win_first = b
             ws = int(block_start[win_first])
@@ -259,14 +306,11 @@ def encode(data: bytes | np.ndarray,
                 block_depth[blks] = dpth.window_depths(win_ptrs,
                                                        block_len[blks])
                 win_ptrs = []
-        streams.append(literals)
-        class_ids.append(S_LITERALS)
-        streams.append(_planes_u16(ml_a))
-        class_ids.append(S_LENGTHS)
-        streams.append(_ra_planes(of_a) if mode == "ra" else _planes_u64(of_a))
-        class_ids.append(S_OFFSETS)
-        streams.append(_planes_u16(ll_a))
-        class_ids.append(S_COMMANDS)
+            streams.extend([literals, _planes_u16(ml_a), _planes_u64(of_a),
+                            _planes_u16(ll_a)])
+    # streams are block-major in class order (literals, lengths, offsets,
+    # commands)
+    class_ids = [S_LITERALS, S_LENGTHS, S_OFFSETS, S_COMMANDS] * n_blocks
 
     # archive-global entropy tables, one per stream class
     hists = np.zeros((N_STREAMS, 256), np.int64)

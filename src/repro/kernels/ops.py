@@ -1,9 +1,25 @@
-"""jit'd wrappers over the Pallas kernels with backend dispatch.
+"""jit'd wrappers over the decode kernels with backend dispatch.
 
 backend:
-  "ref"     — pure-jnp oracle (fast on CPU; what XLA fuses on TPU anyway)
+  "ref"     — pure-jnp implementation (`kernels.ref`), lowered by XLA
   "pallas"  — pl.pallas_call; interpret=True off-TPU (validation mode)
-  "auto"    — "pallas" on TPU, "ref" elsewhere
+  "auto"    — "ref" on every platform
+
+Why "auto" never picks Pallas, on a TPU included: an ahead-of-time compile
+for a TPU v5e (Mosaic lowering, jax 0.9.0 / libtpu 0.0.34) refuses both
+kernels at every block size from 4 KiB to 1 MiB:
+
+  * the block shapes `(1, C)`, `(1, 1)` and `(1, group)` are neither
+    multiples of the (8, 128) tiling nor the full array dims;
+  * with whole-array blocks the LZ77 body still fails on `cumsum`
+    (unimplemented primitive in the Pallas TPU lowering), and the rANS
+    body on its data-dependent `words_ref[...]` gathers ("Cannot do int
+    indexing on TPU").
+
+The pure-jnp path compiles for the chip at all three block sizes
+(`tests/test_tpu_compile.py`). An explicit backend="pallas" still
+compiles with interpret=False on a TPU — and fails loudly there; it never
+runs in interpret mode on the chip.
 """
 from __future__ import annotations
 
@@ -14,17 +30,13 @@ import numpy as np
 from repro.kernels import ref as _ref
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:   # pragma: no cover
-        return False
+def _interpret() -> bool:
+    """Pallas interpret mode everywhere but a TPU."""
+    return jax.default_backend() != "tpu"
 
 
 def _resolve(backend: str) -> str:
-    if backend == "auto":
-        return "pallas" if _on_tpu() else "ref"
-    return backend
+    return "ref" if backend == "auto" else backend
 
 
 def lz77_decode_blocks(lit_lens, match_lens, offsets, n_cmds, literals,
@@ -41,7 +53,7 @@ def lz77_decode_blocks(lit_lens, match_lens, offsets, n_cmds, literals,
     from repro.kernels.lz77_match import lz77_decode_blocks_pallas
     return lz77_decode_blocks_pallas(
         lit_lens, match_lens, offsets, n_cmds, literals, block_len,
-        out_size=out_size, interpret=not _on_tpu(), n_rounds=n_rounds)
+        out_size=out_size, interpret=_interpret(), n_rounds=n_rounds)
 
 
 def rans_decode(words, word_off, n_syms, lanes, class_ids, freqs,
@@ -57,7 +69,7 @@ def rans_decode(words, word_off, n_syms, lanes, class_ids, freqs,
     freqs_t = tuple(map(tuple, np.asarray(freqs).tolist()))
     rows = rans_decode_pallas(words, word_off, n_syms, lanes, class_ids,
                               freqs_t, t_max=t_max, k_max=k_max, group=group,
-                              interpret=not _on_tpu())
+                              interpret=_interpret())
     n = jnp.asarray(n_syms, jnp.int32)
     K = jnp.maximum(jnp.asarray(lanes, jnp.int32), 1)
     return rows, jnp.where(n > 0, -(-n // K), 0)

@@ -1,30 +1,32 @@
-"""Process hygiene for training launches (the olmax `run.sh` idiom,
-in-process).
+"""Process hygiene for launches (the olmax `run.sh` idiom, in-process).
 
-Production JAX launchers front-load three kinds of environment setup
-before the first backend touch:
+Production JAX launchers front-load environment setup before the first
+backend touch:
 
   * allocator — tcmalloc via LD_PRELOAD (needs a re-exec: the loader
     reads LD_PRELOAD before Python runs) + a large-alloc report
     threshold so multi-GB numpy buffers don't spam warnings;
   * log noise — TF_CPP_MIN_LOG_LEVEL=4 silences the libtpu/TF chatter
     that interleaves with step logs;
-  * XLA flags — appended to XLA_FLAGS, keyed by platform: flags like
-    `--xla_step_marker_location=1` (step markers at the outer while
-    loop) only parse on TPU builds; this container's CPU XLA aborts on
-    them, so the table is per-platform and never force-feeds a flag the
-    local build can't parse.
+  * compile cache — `enable_compile_cache()` turns on JAX's persistent
+    compilation cache, so a second run of the same shapes loads its
+    executables instead of compiling them.
 
-Everything is idempotent and respectful of the caller's environment:
-a variable the user already set is never overwritten, a flag already in
-XLA_FLAGS is never duplicated. `apply_process_hygiene()` must run
-before the first jax backend touch (import is fine; device use is not).
+No XLA flags are set here: jaxlib parses XLA_FLAGS for its CPU client on
+every host, a TPU host included, and exits at start-up on a flag that
+client does not know, such as the TPU-only `--xla_step_marker_location`.
+
+Everything is idempotent and respectful of the caller's environment: a
+variable the user already set is never overwritten.
+`apply_process_hygiene()` must run before the first jax backend touch
+(import is fine; device use is not).
 """
 from __future__ import annotations
 
 import os
 import sys
-from typing import Dict, List, Optional
+from pathlib import Path
+from typing import Dict, Optional
 
 # env defaults applied only when unset (user environment wins)
 _ENV_DEFAULTS = {
@@ -34,16 +36,9 @@ _ENV_DEFAULTS = {
     "TF_CPP_MIN_LOG_LEVEL": "4",
 }
 
-# XLA flags by platform. CPU gets none by default: this container's CPU
-# XLA aborts on TPU-scoped flags (verified: --xla_step_marker_location
-# is a hard abort), and the CPU-safe knobs are already defaults.
-_XLA_FLAGS: Dict[str, List[str]] = {
-    "tpu": [
-        "--xla_step_marker_location=1",   # step marker at the outer while
-    ],
-    "cpu": [],
-    "gpu": [],
-}
+# the checkout's own cache directory (git-ignored); a fixed path, because
+# a cache that moves between runs is never hit again
+_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
 
 _TCMALLOC_PATHS = (
     "/usr/lib/x86_64-linux-gnu/libtcmalloc.so.4",
@@ -82,26 +77,29 @@ def maybe_reexec_tcmalloc(enable: bool) -> bool:
     return True        # unreachable; keeps the signature honest
 
 
-def apply_process_hygiene(platform: Optional[str] = None,
-                          extra_xla_flags: Optional[List[str]] = None
-                          ) -> Dict[str, str]:
-    """Set the env defaults + platform-keyed XLA flags. Returns the
-    variables actually changed (empty when the environment already had
-    everything). `platform` defaults to JAX_PLATFORMS/JAX_PLATFORM_NAME
-    or "cpu"; pass "tpu"/"gpu" explicitly on real accelerator launches."""
+def apply_process_hygiene() -> Dict[str, str]:
+    """Set the env defaults. Returns the variables actually changed
+    (empty when the environment already had everything)."""
     changed: Dict[str, str] = {}
     for k, v in _ENV_DEFAULTS.items():
         if k not in os.environ:
             os.environ[k] = v
             changed[k] = v
-    if platform is None:
-        platform = (os.environ.get("JAX_PLATFORMS")
-                    or os.environ.get("JAX_PLATFORM_NAME") or "cpu")
-    platform = platform.split(",")[0].strip().lower() or "cpu"
-    want = list(_XLA_FLAGS.get(platform, [])) + list(extra_xla_flags or [])
-    have = os.environ.get("XLA_FLAGS", "")
-    add = [f for f in want if f.split("=")[0] not in have]
-    if add:
-        os.environ["XLA_FLAGS"] = (have + " " + " ".join(add)).strip()
-        changed["XLA_FLAGS"] = os.environ["XLA_FLAGS"]
     return changed
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its
+    directory. Call before the first compile.
+
+    `JAX_COMPILATION_CACHE_DIR`, when set, is the directory (jax reads
+    the variable itself, so no other is set here); otherwise the cache
+    lives in `<checkout>/.jax_cache/`. Every executable is cached, however
+    fast it compiled: the decode path is many small programs."""
+    import jax
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(_CACHE_DIR)
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    return path

@@ -19,6 +19,7 @@ import jax
 from repro.api import GenomicArchive
 from repro.configs import get_config
 from repro.data.fastq import make_fastq
+from repro.launch.hygiene import enable_compile_cache
 from repro.models.registry import build_model
 from repro.serving.frontend import ServingFrontend
 from repro.serving.serve_step import ReadBatcher, ServeConfig, ServeSession
@@ -55,6 +56,7 @@ def main():
                     default=True,
                     help="reduced model config (--no-reduced = full size)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

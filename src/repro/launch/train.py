@@ -11,8 +11,9 @@ compressed bytes on disk, no re-encode) into a `GenomicArchive`, and
 through DecodePlan/BlockCache while step k runs, `--unroll U` feeds
 (U, B, T) windows (ONE DecodePlan per window) to a `lax.scan`-unrolled
 donated train step. Process hygiene (tcmalloc LD_PRELOAD re-exec,
-platform-keyed XLA flags, log-noise env) applies before the backend
-initializes.
+log-noise env) applies before the backend initializes, and the persistent
+compile cache (`JAX_COMPILATION_CACHE_DIR`, else `<checkout>/.jax_cache`)
+before the first compile.
 
 Full-config multi-pod launches use the same path with the production
 mesh; on this CPU container you run reduced configs (the full configs
@@ -119,6 +120,7 @@ def main():
     ap.add_argument("--tcmalloc", action="store_true",
                     help="re-exec with tcmalloc LD_PRELOADed")
     args = ap.parse_args()
+    hygiene.enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -65,6 +65,17 @@ def test_mode1_equals_mode2(fastq_noisy):
     assert np.array_equal(m1, m2)
 
 
+@pytest.mark.parametrize("block_size", [4096, 1 << 17])
+def test_parallel_ra_encode_matches_serial(fastq_platinum, monkeypatch,
+                                           block_size):
+    """Large "ra" inputs encode their blocks in a spawn process pool; the
+    archive must be byte-identical to the serial encode."""
+    data = fastq_platinum[:300_000]
+    serial = fmt.serialize(enc.encode(data, block_size=block_size))
+    monkeypatch.setattr(enc, "_PARALLEL_MIN_BYTES", 0)
+    assert fmt.serialize(enc.encode(data, block_size=block_size)) == serial
+
+
 def test_serialization_roundtrip(fastq_platinum):
     a = enc.encode(fastq_platinum[:30_000], block_size=4096)
     buf = fmt.serialize(a)

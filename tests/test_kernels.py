@@ -121,3 +121,14 @@ def test_pallas_backend_end_to_end(fastq_noisy):
     a = enc.encode(data, block_size=2048)
     out = Decoder(a, backend="pallas").decode_all()
     np.testing.assert_array_equal(out, np.frombuffer(data, np.uint8))
+
+
+@pytest.mark.parametrize("platform,interpret", [("cpu", True),
+                                                ("tpu", False)])
+def test_backend_dispatch_by_platform(monkeypatch, platform, interpret):
+    """"auto" is the XLA path on every platform; an explicit "pallas"
+    never runs in interpret mode on a TPU."""
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: platform)
+    assert ops._resolve("auto") == "ref"
+    assert ops._resolve("pallas") == "pallas"
+    assert ops._interpret() is interpret
