@@ -37,6 +37,8 @@ import numpy as np
 # no JAX at module level: the encoder's spawn workers re-import this file
 sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
 
+N_READS = 1_000_000               # ~223 MB of 100 bp platinum reads
+SEED = 0
 BLOCK_SIZE = 65536
 CACHE_BLOCKS = 256
 STREAM_BUDGET = 32 << 20          # bytes; far below the ~223 MB corpus
@@ -341,9 +343,6 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--four-chips", action="store_true",
                     help="run only the mesh-partitioned path on 4 chips")
-    ap.add_argument("--reads", type=int, default=1_000_000,
-                    help="corpus size in reads (default 1,000,000)")
-    ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
     from repro.launch.hygiene import apply_process_hygiene, enable_compile_cache
@@ -370,9 +369,9 @@ def main(argv=None) -> int:
             print(f"chip_smoke --four-chips: {len(devices)} TPU devices, "
                   f"need 4", file=sys.stderr)
             return 2
-        run_four_chips(args.reads, args.seed, log)
+        run_four_chips(N_READS, SEED, log)
     else:
-        run_one_chip(args.reads, args.seed, log)
+        run_one_chip(N_READS, SEED, log)
     print(json.dumps({"ok": True, "device": device}), flush=True)
     return 0
 

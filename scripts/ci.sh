@@ -108,15 +108,26 @@ EOF
   # plus the depth-bucketed schedule (ra/depth_bucketed_GBps);
   # bench_compare prints each ra/* row's recorded max_depth and bucket
   # histogram next to its time.
-  # (sharded joins the smoke set report-only: shard/* rows carry the
-  # per-shard resident bytes bench_compare prints next to each row;
-  # train/* rows assert a bit-identical loss trajectory sync-vs-prefetch
+  # (train/* rows assert a bit-identical loss trajectory sync-vs-prefetch
   # and carry the measured speedup in their derived field;
   # resil/* rows are report-only: parity storage cost and one-block
   # parity-reconstruction latency, with the reconstructed/quarantined
   # counters printed next to each row)
   python -m benchmarks.run --small \
-    --only index,fetch_batch,query,blocksize,cache,random_access,tune,serving,sharded,train,resilience \
+    --only index,fetch_batch,query,blocksize,cache,random_access,tune,serving,train,resilience \
     --json bench_current.json
+  # the sharded table runs in its own process on 8 forced host devices
+  # (mesh widths 1-8); its report-only shard/* rows, with the per-shard
+  # resident bytes bench_compare prints next to each, join the snapshot
+  XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+    python -m benchmarks.run --small --only sharded --json bench_sharded.json
+  python - <<'EOF'
+import json
+cur = json.load(open("bench_current.json"))
+cur["rows"] += json.load(open("bench_sharded.json"))["rows"]
+with open("bench_current.json", "w") as f:
+    json.dump(cur, f, indent=2, sort_keys=True)
+    f.write("\n")
+EOF
   python scripts/bench_compare.py BENCH_baseline.json bench_current.json
 fi

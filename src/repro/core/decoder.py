@@ -36,6 +36,9 @@ from repro.core.format import (FNV_OFFSET, N_STREAMS, S_COMMANDS, S_LENGTHS,
 # in launches of this many blocks.
 MAX_LAUNCH_BLOCKS = 512
 
+# f32 represents every integer up to this exactly (24-bit significand).
+F32_EXACT_INTS = 1 << 24
+
 
 class BlockDigestError(ValueError):
     """A decoded block's FNV-1a-64 digest does not match the archive's."""
@@ -160,18 +163,22 @@ def _linearize(rows: jnp.ndarray, n: jnp.ndarray, k: jnp.ndarray,
 
     Symbol i lives at (i // K) * k_max + (i % K); i >= n → 0.
 
-    The quotient is taken in f32 and corrected by one: the TPU compiler
-    spends over 100 s on an s32 divide of a large iota by a per-row
-    divisor (256 x 64 KiB), and f32 holds every i < 2^24 exactly, so
-    the rounded quotient is off by at most one.
+    Up to 2^24 bytes the quotient is taken in f32 and corrected by one:
+    the TPU compiler spends over 100 s on an s32 divide of a large iota
+    by a per-row divisor (256 x 64 KiB), and f32 holds every i < 2^24
+    exactly, so the rounded quotient is off by at most one. Longer
+    streams (blocks of 16 MiB and more) keep the s32 divide; the choice
+    is made by the static `out_len`.
     """
-    assert out_len < 2**24, out_len
     i = jnp.arange(out_len, dtype=jnp.int32)[None, :]
     k = jnp.maximum(k, 1)[:, None]
-    q = jnp.floor(i.astype(jnp.float32) / k.astype(jnp.float32)
-                  ).astype(jnp.int32)
-    r = i - q * k
-    q = jnp.where(r < 0, q - 1, jnp.where(r >= k, q + 1, q))
+    if out_len > F32_EXACT_INTS:
+        q = i // k
+    else:
+        q = jnp.floor(i.astype(jnp.float32) / k.astype(jnp.float32)
+                      ).astype(jnp.int32)
+        r = i - q * k
+        q = jnp.where(r < 0, q - 1, jnp.where(r >= k, q + 1, q))
     idx = q * k_max + (i - q * k)
     idx = jnp.clip(idx, 0, rows.shape[1] - 1)
     vals = jnp.take_along_axis(rows, idx, axis=1)
@@ -978,8 +985,9 @@ class Decoder:
                    on_error: str = "raise") -> np.ndarray:
         """Whole-file decode; never materializes more than one chunk of
         decompressed output at a time (paper §5 v7-RA). `chunk_blocks`
-        defaults to `MAX_LAUNCH_BLOCKS` for "ra" archives and to the whole
-        archive for global ones (their decode windows cross blocks).
+        (the most blocks per launch) defaults to `MAX_LAUNCH_BLOCKS` for
+        "ra" archives and to the whole archive for global ones (their
+        decode windows cross blocks).
         Compatibility shim over `StreamingExecutor`.
 
         verify=True additionally checks `file_fnv` over the block digest
@@ -997,7 +1005,10 @@ class Decoder:
         if chunk_blocks is None:
             chunk_blocks = (min(n_blocks, MAX_LAUNCH_BLOCKS)
                             if self.da.mode == "ra" else n_blocks)
-        step = int(chunk_blocks)
+        # the launches split the archive evenly, so the last one pads
+        # fewer than one block per launch
+        n_launches = -(-n_blocks // int(chunk_blocks))
+        step = -(-n_blocks // n_launches)
         if verify:
             a = self.archive
             if file_digest(a.block_fnv) != a.file_fnv:

@@ -18,7 +18,7 @@ import chip_smoke  # noqa: E402
 def _run(script: Path, cwd: Path) -> subprocess.CompletedProcess:
     env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
     env["JAX_PLATFORMS"] = "cpu"
-    return subprocess.run([sys.executable, str(script), "--reads", "100"],
+    return subprocess.run([sys.executable, str(script)],
                           cwd=cwd, env=env, capture_output=True, text=True,
                           timeout=300)
 

@@ -197,6 +197,43 @@ def test_verify_clean_and_corrupted(fastq_platinum):
         dec.Decoder(bad2, backend="ref").decode_all(verify=True)
 
 
+def test_decode_all_launches_split_evenly(fastq_platinum, monkeypatch):
+    """Verified decode_all splits the archive into launches of one shape,
+    padding fewer than one block per launch: 10 blocks under a 6-block
+    cap decode as 2 x 5, not 2 x 6."""
+    data = fastq_platinum[:40_000]
+    d = dec.Decoder(enc.encode(data, block_size=4096), backend="ref")
+    launches = []
+    decode_blocks = d.decode_blocks
+
+    def record(ids, **kw):
+        launches.append(len(ids))
+        return decode_blocks(ids, **kw)
+
+    monkeypatch.setattr(d, "decode_blocks", record)
+    out = d.decode_all(chunk_blocks=6, verify=True)
+    assert np.array_equal(out, np.frombuffer(data, np.uint8))
+    assert d.archive.n_blocks == 10 and launches == [5, 5]
+
+
+@pytest.mark.parametrize("out_len", [dec.F32_EXACT_INTS,
+                                     dec.F32_EXACT_INTS + 5])
+def test_linearize_exact_at_f32_limit(out_len):
+    """_linearize's quotient stays exact on both sides of 2^24 (the f32
+    path up to it, the s32 divide past it); checked at both ends."""
+    import jax.numpy as jnp
+    k, k_max = 7, 8
+    rows = (np.arange(-(-out_len // k) * k_max) % 251).astype(np.uint8)
+    n = out_len - 3
+    got = np.asarray(dec._linearize(jnp.asarray(rows[None]),
+                                    jnp.asarray([n], jnp.int32),
+                                    jnp.asarray([k], jnp.int32),
+                                    out_len, k_max=k_max))[0]
+    for i in (np.arange(4096), np.arange(out_len - 4096, out_len)):
+        want = np.where(i < n, rows[(i // k) * k_max + i % k], 0)
+        assert np.array_equal(got[i], want)
+
+
 def test_device_fnv_matches_host_recurrence(rng):
     """The u32-limb lax.scan digest == format.fnv1a64_u64_stride for
     ragged row lengths (incl. non-multiple-of-8 and zero)."""
