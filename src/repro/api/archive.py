@@ -327,7 +327,9 @@ class GenomicArchive:
 
     def cache_info(self) -> dict:
         """Decoded-block cache counters: hits/misses/evictions/installs,
-        bytes_resident, decode_launches, policy (zeros when disabled)."""
+        bytes_resident, decode_launches, policy (zeros when disabled),
+        and the decoder's decode counters as `decoder_launches`,
+        `decoder_rows`, `decoder_blocks`, `decoder_pad_rows`."""
         return self.store.cache_info()
 
     def clear_cache(self) -> None:

@@ -48,6 +48,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro import trace
 from repro.api.plan import CachePlan, split_cache_hits
 from repro.core.residency import _pad_pow2
 
@@ -405,6 +406,7 @@ class BlockCache:
                 "policy": self.policy.name}
 
     # ---------------------------------------------------------------- plan
+    @trace.spanned(trace.CACHE_PLAN)
     def plan(self, uniq: np.ndarray) -> CachePlan:
         """Unique covering set → CachePlan. Mutates the slot maps (evicted
         blocks leave, admitted misses claim their slots) and the policy's
@@ -497,7 +499,8 @@ class BlockCache:
             return jnp.zeros((0, self.block_size), jnp.uint8)
         if cp.miss_blocks.size == 0:
             slots = _pad_pow2(cp.src_idx.astype(np.int32))
-            return _gather_slots(self.buf, jnp.asarray(slots))[:U]
+            with trace.span(trace.CACHE_GATHER):
+                return _gather_slots(self.buf, jnp.asarray(slots))[:U]
         miss_sel = _pad_pow2(cp.miss_blocks.astype(np.int32))
         try:
             miss_rows = decode(miss_sel)
@@ -509,9 +512,10 @@ class BlockCache:
                              fill=self.capacity)   # same pow2 as miss_sel
             src_idx = _pad_pow2(cp.src_idx.astype(np.int32))
             src_is_miss = _pad_pow2(cp.src_is_miss)
-            self.buf, rows = _install_gather(
-                self.buf, miss_rows, jnp.asarray(inst),
-                jnp.asarray(src_is_miss), jnp.asarray(src_idx))
+            with trace.span(trace.CACHE_INSTALL):
+                self.buf, rows = _install_gather(
+                    self.buf, miss_rows, jnp.asarray(inst),
+                    jnp.asarray(src_is_miss), jnp.asarray(src_idx))
         except BaseException:
             # plan() already marked the misses resident, and a failed
             # _install_gather may have consumed the donated buffer —

@@ -26,6 +26,7 @@ import numpy as np
 
 import jax.numpy as jnp
 
+from repro import trace
 from repro.api.address import Address
 from repro.api.plan import DecodePlan, QueryPlanner, anchor_floor
 from repro.core.residency import (_fetch_dev_jit, _fetch_reads_jit,
@@ -131,9 +132,11 @@ class DeviceExecutor:
             # of its covering blocks is (its bytes include zeroed rows)
             bad_row = np.isin(uniq, dec.last_bad_blocks)
             self.last_corrupt = bad_row[row_map].any(axis=1)[:B]
-        out = _gather_jit(rows, jnp.asarray(row_map), jnp.asarray(r0),
-                          jnp.asarray(plan.lengths.astype(np.int32)),
-                          block_size=plan.block_size, max_len=plan.max_len)
+        with trace.span(trace.GATHER):
+            out = _gather_jit(rows, jnp.asarray(row_map), jnp.asarray(r0),
+                              jnp.asarray(plan.lengths.astype(np.int32)),
+                              block_size=plan.block_size,
+                              max_len=plan.max_len)
         return out[:B], lens
 
 
@@ -349,6 +352,11 @@ class StreamingExecutor:
             yield self._execute(cur)
 
     def _execute(self, pieces) -> np.ndarray:
+        """One chunk, in a host span numbered by the chunks before it."""
+        with trace.span(trace.STREAM_CHUNK, chunk=len(self.chunk_log)):
+            return self._execute_chunk(pieces)
+
+    def _execute_chunk(self, pieces) -> np.ndarray:
         bs = self.store.block_size
         starts = np.asarray([p[0] for p in pieces], np.int64)
         lengths = np.asarray([p[1] for p in pieces], np.int64)
@@ -379,13 +387,16 @@ class StreamingExecutor:
             # for the same budget reason the selection is not pow2-padded
             rows = decode(uniq.astype(np.int32), verify=self.verify,
                           pad_groups=False, on_error=self.on_error)
-        out = _gather_jit(rows, jnp.asarray(row_map), jnp.asarray(r0),
-                          jnp.asarray(plan.lengths.astype(np.int32)),
-                          block_size=bs, max_len=plan.max_len)
-        host = np.asarray(out[:plan.n_queries])
-        parts = [host[i, :int(lengths[i])] for i in range(len(pieces))]
-        payload = (np.concatenate(parts) if parts
-                   else np.zeros(0, np.uint8))
+        with trace.span(trace.GATHER):
+            out = _gather_jit(rows, jnp.asarray(row_map), jnp.asarray(r0),
+                              jnp.asarray(plan.lengths.astype(np.int32)),
+                              block_size=bs, max_len=plan.max_len)
+        with trace.span(trace.TO_HOST):
+            host = np.asarray(out[:plan.n_queries])
+        with trace.span(trace.STREAM_ASSEMBLE):
+            parts = [host[i, :int(lengths[i])] for i in range(len(pieces))]
+            payload = (np.concatenate(parts) if parts
+                       else np.zeros(0, np.uint8))
         # decoded_blocks_last is what the decoder actually materialized —
         # == uniq for "ra", the summed anchor windows for checkpointed
         # wavefronts, the whole prefix for anchor-free global archives
@@ -523,7 +534,9 @@ class ShardedExecutor:
                     rows = dec.decode_blocks(
                         _pad_pow2(uniq.astype(np.int32)), verify=True,
                         on_error=self.on_error)[:uniq.size]
-        out = _gather_jit(rows, jnp.asarray(row_map), jnp.asarray(r0),
-                          jnp.asarray(plan.lengths.astype(np.int32)),
-                          block_size=plan.block_size, max_len=plan.max_len)
+        with trace.span(trace.GATHER):
+            out = _gather_jit(rows, jnp.asarray(row_map), jnp.asarray(r0),
+                              jnp.asarray(plan.lengths.astype(np.int32)),
+                              block_size=plan.block_size,
+                              max_len=plan.max_len)
         return out[:B], jnp.asarray(plan.lengths[:B].astype(np.int32))

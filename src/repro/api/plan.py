@@ -21,6 +21,7 @@ from typing import Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import trace
 from repro.api.address import (Address, ByteRange, NameTable, ReadId, Region,
                                normalize)
 
@@ -340,6 +341,7 @@ class QueryPlanner:
         return self.store.decoder.block_rounds
 
     # ------------------------------------------------------------ fast paths
+    @trace.spanned(trace.PLAN)
     def plan_read_ids(self, ids: np.ndarray) -> DecodePlan:
         """All-ReadId batches: geometry is store-static and the covering set
         resolves from the device start table (zero per-query host math)."""
@@ -384,6 +386,7 @@ class QueryPlanner:
             max_span=record_bytes // self.block_size + 2,
             max_depth=self.max_depth, block_rounds=self.block_rounds)
 
+    @trace.spanned(trace.PLAN)
     def plan_spans(self, starts: np.ndarray, lengths: np.ndarray,
                    max_len: Optional[int] = None) -> DecodePlan:
         """Raw absolute byte spans (ByteRange batches, streaming chunks).
@@ -490,6 +493,7 @@ class QueryPlanner:
             whole = whole and lo == 0 and hi == e - s
         return starts, lengths, (ids if whole and typed else None)
 
+    @trace.spanned(trace.PLAN)
     def plan(self, addrs: Sequence[Address]) -> DecodePlan:
         """The general entry: any mix of addresses → one DecodePlan. Pure
         whole-record batches keep the device start-table fast path; span

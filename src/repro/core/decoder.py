@@ -22,6 +22,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro import trace
 from repro.core import depth as dpth
 from repro.core import entropy as ent
 from repro.core.format import (FNV_OFFSET, N_STREAMS, S_COMMANDS, S_LENGTHS,
@@ -274,20 +275,22 @@ def _entropy_decode_sel(da: DeviceArchive, sel: jnp.ndarray, backend: str):
     flat_lanes = lanes.reshape(-1)
     cls = jnp.tile(jnp.arange(N_STREAMS, dtype=jnp.int32), B)
     t_max = max(da.t_max_lit, da.t_max_cmd)
-    rows, _ = ops.rans_decode(
-        da.words, flat_off, flat_nsym, flat_lanes, cls, da.freqs,
-        t_max=t_max, backend=backend)
+    with jax.named_scope(trace.DECODE_RANS):
+        rows, _ = ops.rans_decode(
+            da.words, flat_off, flat_nsym, flat_lanes, cls, da.freqs,
+            t_max=t_max, backend=backend)
     rows = rows.reshape(B, N_STREAMS, -1)
 
     def lin(col, out_len):
         return _linearize(rows[:, col], nsym[:, col], lanes[:, col], out_len)
 
-    return {
-        "literals": lin(S_LITERALS, da.block_size),
-        "lengths": lin(S_LENGTHS, 2 * da.max_cmds),
-        "offsets": lin(S_OFFSETS, off_planes * da.max_cmds),
-        "commands": lin(S_COMMANDS, 2 * da.max_cmds),
-    }
+    with jax.named_scope(trace.DECODE_LINEARIZE):
+        return {
+            "literals": lin(S_LITERALS, da.block_size),
+            "lengths": lin(S_LENGTHS, 2 * da.max_cmds),
+            "offsets": lin(S_OFFSETS, off_planes * da.max_cmds),
+            "commands": lin(S_COMMANDS, 2 * da.max_cmds),
+        }
 
 
 def _entropy_decode_host(a: Archive, sel: np.ndarray):
@@ -340,18 +343,20 @@ def _match_phase(da_mode: str, streams, n_cmds, block_len, block_start,
     legacy depth-free archive — the ref resolver early-exits via
     while_loop, pallas falls back to log2(block)."""
     from repro.kernels import ops, ref
-    lit_lens = _u16_from_planes(streams["commands"], n_cmds, max_cmds)
-    match_lens = _u16_from_planes(streams["lengths"], n_cmds, max_cmds)
-    if offset_bytes == 2:
-        offsets = _u16_from_planes(streams["offsets"], n_cmds, max_cmds)
-    elif offset_bytes == 4:
-        # 4-plane block-local offsets ("ra", block_size > 0xFFFF)
-        offsets = _u32_from_planes(streams["offsets"], n_cmds, max_cmds)
-    else:
-        # 8-plane global offsets: full low-32-bit word, wraparound
-        # semantics — rebased below BEFORE any narrowing, so windows
-        # starting past 2 GiB resolve exactly
-        offsets = _u64lo_from_planes(streams["offsets"], n_cmds, max_cmds)
+    with jax.named_scope(trace.DECODE_EXPAND):
+        lit_lens = _u16_from_planes(streams["commands"], n_cmds, max_cmds)
+        match_lens = _u16_from_planes(streams["lengths"], n_cmds, max_cmds)
+        if offset_bytes == 2:
+            offsets = _u16_from_planes(streams["offsets"], n_cmds, max_cmds)
+        elif offset_bytes == 4:
+            # 4-plane block-local offsets ("ra", block_size > 0xFFFF)
+            offsets = _u32_from_planes(streams["offsets"], n_cmds, max_cmds)
+        else:
+            # 8-plane global offsets: full low-32-bit word, wraparound
+            # semantics — rebased below BEFORE any narrowing, so windows
+            # starting past 2 GiB resolve exactly
+            offsets = _u64lo_from_planes(streams["offsets"], n_cmds,
+                                         max_cmds)
 
     if da_mode == "ra":
         return ops.lz77_decode_blocks(
@@ -422,6 +427,7 @@ def _fnv_mul_u32(hi: jnp.ndarray, lo: jnp.ndarray):
     return t_hi + (lo << 8), t_lo
 
 
+@jax.named_scope(trace.VERIFY_FNV)
 def _fnv_rows_core(rows: jnp.ndarray, block_len: jnp.ndarray):
     """(B, S) u8 decoded rows → per-row 8-byte-stride FNV-1a-64 as u32
     (hi, lo) pairs: the device twin of `format.fnv1a64_u64_stride`.
@@ -485,6 +491,9 @@ class Decoder:
         }
         self._store_view = None
         self.decoded_blocks_last = 0
+        # cumulative over the decoder's life (`decode_info`): host ints
+        self._decode_counts = {"launches": 0, "rows": 0, "blocks": 0,
+                               "pad_rows": 0}
         # ---- depth-bucketed round schedule (PR 6) ----
         # per-block resolve-round counts, pow2-bucketed archive-wide
         # (core.depth.scheduled_rounds): a selection decodes in one launch
@@ -571,6 +580,33 @@ class Decoder:
         return (da.block_size, da.n_blocks, da.max_cmds, da.t_max_lit,
                 da.t_max_cmd, da.mode, da.entropy, da.offset_bytes, total,
                 self._freqs_host, rounds)
+
+    def decode_info(self) -> dict:
+        """Cumulative Mode-2 decode counters: `launches` (dispatches of
+        the jitted selection decode), `rows` (rows they materialized),
+        `blocks` (distinct real blocks among them, per launch) and
+        `pad_rows` (rows that repeat a block of their launch: the pow2
+        padding of the cache's miss batch and of the depth groups)."""
+        return dict(self._decode_counts)
+
+    def _count_launch(self, ids: np.ndarray) -> None:
+        n, distinct = int(ids.size), int(np.unique(ids).size)
+        c = self._decode_counts
+        c["launches"] += 1
+        c["rows"] += n
+        c["blocks"] += distinct
+        c["pad_rows"] += n - distinct
+
+    def _launch(self, sel_np: np.ndarray, meta: tuple) -> jnp.ndarray:
+        """One `_decode_sel_jit` dispatch of the block ids `sel_np`,
+        spanned and counted; `meta` is `_meta(...)`, whose last field is
+        the launch's resolve rounds."""
+        with trace.span(trace.DECODE_LAUNCH, rows=int(sel_np.size),
+                        rounds=meta[-1]):
+            out = _decode_sel_jit(self.arrays, jnp.asarray(sel_np, jnp.int32),
+                                  meta, self.backend)
+        self._count_launch(sel_np)
+        return out
 
     # ------------------------------------------------- depth-bucket schedule
     @property
@@ -782,12 +818,10 @@ class Decoder:
         window, not the archive — total_size scales with the window."""
         L = last - first + 1
         _check_window_bytes(first, last, self.da.block_size)
-        wsel = jnp.arange(first, last + 1, dtype=jnp.int32)
         n_rounds = self._rounds_for_span(first, last)
-        flat = _decode_sel_jit(self.arrays, wsel,
-                               self._meta(L, total=L * self.da.block_size,
-                                          n_rounds=n_rounds),
-                               self.backend)
+        flat = self._launch(np.arange(first, last + 1, dtype=np.int32),
+                            self._meta(L, total=L * self.da.block_size,
+                                       n_rounds=n_rounds))
         self.launch_rounds_last.append(n_rounds)
         self.decoded_blocks_last += L
         rows = flat.reshape(L, self.da.block_size)
@@ -886,21 +920,17 @@ class Decoder:
 
     def _decode_blocks_raw(self, sel_np: np.ndarray,
                            pad_groups: bool = True) -> jnp.ndarray:
-        sel = jnp.asarray(sel_np, jnp.int32)
         if self.da.mode == "global":
             return self._decode_global_rows(np.asarray(sel_np, np.int64))
         groups = self._ra_groups(sel_np)
         if groups is None:
-            out = _decode_sel_jit(self.arrays, sel,
-                                  self._meta(len(sel_np)), self.backend)
+            out = self._launch(sel_np, self._meta(len(sel_np)))
             self.launch_rounds_last.append(self.da.max_depth)
             self.decoded_blocks_last = int(sel_np.size)
             return out
         return self._assemble_ra_groups(
             sel_np, groups,
-            lambda g, r: _decode_sel_jit(
-                self.arrays, jnp.asarray(g),
-                self._meta(g.size, n_rounds=r), self.backend),
+            lambda g, r: self._launch(g, self._meta(g.size, n_rounds=r)),
             pad_groups)
 
     def decode_blocks_host_entropy(self, sel, verify: bool = False,

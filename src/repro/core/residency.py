@@ -220,6 +220,15 @@ class CompressedResidentStore:
         return 0
 
     def cache_info(self) -> dict:
+        """The block cache's counters, and the decoder's cumulative
+        decode counters behind its misses (`Decoder.decode_info`) under
+        `decoder_<counter>`."""
+        info = self._cache_counters()
+        info.update({f"decoder_{k}": v
+                     for k, v in self.decoder.decode_info().items()})
+        return info
+
+    def _cache_counters(self) -> dict:
         if self._cache is None:
             # when only the mesh-partitioned residency carries a cache,
             # its per-shard counters ARE the store's cache accounting

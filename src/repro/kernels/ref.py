@@ -24,6 +24,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro import trace
 from repro.core.depth import log2_rounds  # canonical (jax-free) home
 
 __all__ = ["log2_rounds", "expand_pointers", "resolve_pointers",
@@ -129,9 +130,11 @@ def lz77_decode_block_ref(lit_lens, match_lens, offsets, n_cmds, literals,
                           block_len, out_size: int,
                           n_rounds: Optional[int] = None):
     """Decode ONE self-contained block (oracle for the Pallas kernel)."""
-    ptr = expand_pointers(lit_lens, match_lens, offsets, n_cmds, block_len,
-                          out_size)
-    return resolve_pointers(ptr, literals, n_rounds)
+    with jax.named_scope(trace.DECODE_EXPAND):
+        ptr = expand_pointers(lit_lens, match_lens, offsets, n_cmds,
+                              block_len, out_size)
+    with jax.named_scope(trace.DECODE_RESOLVE):
+        return resolve_pointers(ptr, literals, n_rounds)
 
 
 def lz77_decode_blocks_ref(lit_lens, match_lens, offsets, n_cmds, literals,
@@ -159,36 +162,40 @@ def lz77_decode_global_ref(lit_lens, match_lens, offsets, n_cmds, literals,
     literals: (B, max_lit) per-block literal arrays; lit_base: global literal
     index base per block (exclusive cumsum of literal counts).
     """
-    B = lit_lens.shape[0]
+    with jax.named_scope(trace.DECODE_EXPAND):
+        B = lit_lens.shape[0]
 
-    def one(ll, mlen, off, nc, bstart, blen, lbase):
-        ptr = expand_pointers(ll, mlen, off, nc, blen, out_size, base=bstart)
-        # matches already point at absolute positions (base=bstart above);
-        # literals shift by the block's global literal base.
-        i_local = jnp.arange(out_size, dtype=jnp.int32)
-        is_lit = ptr < 0
-        gl = -(jnp.where(is_lit, ptr, -1) + 1) + lbase
-        gptr = jnp.where(is_lit, -(gl + 1), ptr)
-        valid = i_local < blen
-        return jnp.where(valid, gptr, -1)
+        def one(ll, mlen, off, nc, bstart, blen, lbase):
+            ptr = expand_pointers(ll, mlen, off, nc, blen, out_size,
+                                  base=bstart)
+            # matches already point at absolute positions (base=bstart
+            # above); literals shift by the block's global literal base.
+            i_local = jnp.arange(out_size, dtype=jnp.int32)
+            is_lit = ptr < 0
+            gl = -(jnp.where(is_lit, ptr, -1) + 1) + lbase
+            gptr = jnp.where(is_lit, -(gl + 1), ptr)
+            valid = i_local < blen
+            return jnp.where(valid, gptr, -1)
 
-    gptr = jax.vmap(one)(lit_lens, match_lens, offsets, n_cmds,
-                         block_start.astype(jnp.int32),
-                         block_len, lit_base.astype(jnp.int32))
-    # scatter per-block pointer rows into the flat output space
-    flat = jnp.full(total_size, -1, jnp.int32)
-    pos = (block_start[:, None].astype(jnp.int32)
-           + jnp.arange(out_size, dtype=jnp.int32)[None, :])
-    keep = (jnp.arange(out_size, dtype=jnp.int32)[None, :]
-            < block_len[:, None])
-    flat = flat.at[jnp.where(keep, pos, total_size)].set(
-        jnp.where(keep, gptr, -1), mode="drop")
+        gptr = jax.vmap(one)(lit_lens, match_lens, offsets, n_cmds,
+                             block_start.astype(jnp.int32),
+                             block_len, lit_base.astype(jnp.int32))
+        # scatter per-block pointer rows into the flat output space
+        flat = jnp.full(total_size, -1, jnp.int32)
+        pos = (block_start[:, None].astype(jnp.int32)
+               + jnp.arange(out_size, dtype=jnp.int32)[None, :])
+        keep = (jnp.arange(out_size, dtype=jnp.int32)[None, :]
+                < block_len[:, None])
+        flat = flat.at[jnp.where(keep, pos, total_size)].set(
+            jnp.where(keep, gptr, -1), mode="drop")
 
-    lit_flat = literals.reshape(-1)
-    # global literal index -> (block, local) via lit_base is already folded in
-    flat = resolve_rounds(flat, n_rounds)
-    gl = jnp.clip(-flat - 1, 0, lit_flat.shape[0] - 1)
-    return lit_flat[gl]
+    with jax.named_scope(trace.DECODE_RESOLVE):
+        lit_flat = literals.reshape(-1)
+        # global literal index -> (block, local) via lit_base is already
+        # folded in
+        flat = resolve_rounds(flat, n_rounds)
+        gl = jnp.clip(-flat - 1, 0, lit_flat.shape[0] - 1)
+        return lit_flat[gl]
 
 
 def rans_decode_ref(words, word_off, n_syms, lanes, class_ids, freqs,

@@ -29,6 +29,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro import trace
 from repro.core.format import block_payload_bounds
 
 
@@ -60,6 +61,7 @@ def build_parity(words: np.ndarray, starts: np.ndarray, ends: np.ndarray,
 
 
 @jax.jit
+@jax.named_scope(trace.PARITY_XOR)
 def _xor_rebuild(words, sib_start, sib_len, parity_row, bad_start, bad_len):
     """ONE jitted XOR-gather: fold the sibling payloads into the parity
     row (rebuilt = parity XOR siblings), then blend the first `bad_len`

@@ -43,6 +43,7 @@ from typing import Dict, List, Mapping, Optional, Union
 
 import numpy as np
 
+from repro import trace
 from repro.api.archive import GenomicArchive
 from repro.serving.admission import ServiceEstimator
 from repro.serving.serve_step import ReadBatcher
@@ -266,6 +267,10 @@ class ServingFrontend:
         coalesce the rest per (archive, tenant), and dispatch each group
         as ONE batched decode. Returns the number of requests resolved
         (served + shed) this cycle."""
+        with trace.span(trace.FRONTEND_STEP, step=self.steps):
+            return self._step()
+
+    def _step(self) -> int:
         now = self._now_us()
         batch: List[_Request] = []
         resolved = 0

@@ -27,6 +27,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro import trace
 from repro.api.archive import GenomicArchive
 
 
@@ -128,7 +129,8 @@ class ReadBatcher:
             rows, lens = self.store.fetch_reads(uniq, mode2=mode2,
                                                 verify=self.verify,
                                                 on_error=self.on_error)
-            rows, lens = np.asarray(rows), np.asarray(lens)
+            with trace.span(trace.TO_HOST):
+                rows, lens = np.asarray(rows), np.asarray(lens)
             lc = np.asarray(self.store.last_corrupt)
             if lc.size != uniq.size:
                 lc = np.zeros(uniq.size, bool)
