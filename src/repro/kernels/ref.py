@@ -1,9 +1,17 @@
 """Pure-jnp oracles for the Pallas kernels (DESIGN.md §3.1).
 
-The LZ77 match phase is re-derived for a vector machine: command expansion is
-a cumsum + binary search over command ends (the Pallas kernel body uses the
-equivalent scatter + cumsum), match self-overlap folds via the modulo trick,
-and cross-command dependencies resolve with pointer doubling.
+The LZ77 match phase is re-derived for a vector machine: command expansion
+scatters each command's field steps at its end and takes their prefix sum
+(the Pallas kernel body scatters command-end marks and gathers the fields),
+match self-overlap folds via the modulo trick, and cross-command
+dependencies resolve with pointer doubling.
+
+On a TPU v5e the expansion takes 46 ms over 16 x 1 MiB blocks and the
+whole decode compiles in 6.0 s. Its layout is set by compile time, which
+the code does not show: compiled for a v5e, a flat scatter and prefix sum
+over 1 to 8 rows of 1 MiB took the compiler several times as long as rows
+of `_ROW` bytes summed in two levels, and an s32 remainder behind them 1.5
+to 4.5 times as long as `_fold` (PERF.md §5).
 
 Resolution rounds come in three flavors:
 
@@ -33,6 +41,23 @@ __all__ = ["log2_rounds", "expand_pointers", "resolve_pointers",
            "rans_decode_ref"]
 
 
+_ROW = 1024   # bytes a row of the expansion's two-level prefix sum
+
+
+def _fold(k, d):
+    """`k mod d` for the bytes of a match: 0 <= k < 2**16 (a u16 match
+    length bounds k) and d >= 1. An f32 quotient is within one of the
+    true one there (k and d exact, far under 2**24), so one correction
+    each way makes it exact; k < 0, a literal byte's, gives 0. Behind the
+    prefix sums an s32 remainder takes the v5e compiler 1.5 to 4.5 times
+    as long as this, and runs no faster."""
+    k = jnp.maximum(k, 0)
+    q = jnp.floor(k.astype(jnp.float32) / d.astype(jnp.float32))
+    r = k - q.astype(jnp.int32) * d
+    r = jnp.where(r < 0, r + d, r)
+    return jnp.where(r >= d, r - d, r)
+
+
 def expand_pointers(lit_lens, match_lens, offsets, n_cmds, block_len,
                     out_size: int, base=0):
     """Per-output-byte source pointers for ONE block.
@@ -58,24 +83,35 @@ def expand_pointers(lit_lens, match_lens, offsets, n_cmds, block_len,
     P = cum_tot - tot                              # command start positions
     cum_lit = jnp.cumsum(ll) - ll                  # literal base per command
 
-    # command-of-byte = number of valid commands ending at or before the
-    # byte: a binary search over the (non-decreasing) command ends. The
-    # equivalent scatter(+1 at command ends) + cumsum takes the TPU
-    # compiler ~14 s per 256 x 64 KiB selection; the search ~2 s.
-    i = jnp.arange(out_size, dtype=jnp.int32)
-    cmd_of = jnp.minimum(jnp.searchsorted(cum_tot, i, side="right"),
-                         n_cmds).astype(jnp.int32)
-    cmd_of = jnp.minimum(cmd_of, C - 1)
+    # Byte i belongs to command min(#{c : cum_tot[c] <= i}, n_cmds, C - 1)
+    # and reads three of its fields: the local match start, the literal
+    # index less the command start, and the offset. Each field is a step
+    # function of i that moves only at command ends, so it is the prefix
+    # sum of its steps scattered at the ends (zero-length commands add up
+    # at one end, ends past the last row drop, i32 wraparound keeps the
+    # sum exact). No per-byte gather: one from a command table costs a
+    # full pass over the bytes, a prefix sum under a hundredth of that on
+    # a v5e. The bytes are laid out in rows of _ROW and summed in two
+    # levels, within rows and then over row totals.
+    rows = -(-out_size // _ROW)
+    fields = jnp.stack([P + ll, cum_lit - P, offsets])
+    count = jnp.minimum(jnp.arange(C + 1, dtype=jnp.int32),
+                        jnp.minimum(n_cmds, C - 1))
+    after = fields[:, count]                 # field after k command ends
+    steps = jnp.zeros((3, rows, _ROW), jnp.int32).at[
+        :, cum_tot // _ROW, cum_tot % _ROW].add(
+        after[:, 1:] - after[:, :-1], mode="drop")
+    within = jnp.cumsum(steps, axis=2)
+    row_sum = within[:, :, -1]
+    runs = within + (jnp.cumsum(row_sum, axis=1) - row_sum)[:, :, None]
+    mstart, lit_base, off = (runs.reshape(3, rows * _ROW)[:, :out_size]
+                             + after[:, :1])
 
-    rel = i - P[cmd_of]
-    is_lit = rel < ll[cmd_of]
-    lit_idx = cum_lit[cmd_of] + rel
+    i = jnp.arange(out_size, dtype=jnp.int32)
     # match source with self-overlap folding (dest start in `base` coords)
-    mstart = base + P[cmd_of] + ll[cmd_of]
-    d = jnp.maximum(mstart - offsets[cmd_of], 1)   # distance >= 1
-    k = rel - ll[cmd_of]
-    mptr = offsets[cmd_of] + jnp.remainder(k, d)
-    ptr = jnp.where(is_lit, -(lit_idx + 1), mptr)
+    d = jnp.maximum(base + mstart - off, 1)        # distance >= 1
+    mptr = off + _fold(i - mstart, d)
+    ptr = jnp.where(i < mstart, -(lit_base + i + 1), mptr)
     ptr = jnp.where(i < block_len, ptr, -1)        # pad bytes → literal 0
     return ptr
 
