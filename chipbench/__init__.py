@@ -4,7 +4,10 @@ One run measures one cell of `BENCHMARK.json` on the accelerator:
 
     python3 chipbench/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
-Everything one configuration, traffic mix or per-layer metric needs sits
-in a file of its own (`configs/<config>.json`, `traffic/<mix>.json`,
-`metrics/<metric>.py`), found by the names in `BENCHMARK.json`.
+Everything one deployment, traffic mix or per-layer metric needs sits in
+files of its own, found by the names in `BENCHMARK.json` (see
+`harness`): its configuration (`configs/<config>.json`), corpus module
+(`corpora/<corpus>.py`), traffic mix (`traffic/<mix>.json`), request
+driver (`drivers/<driver>.py`), per-layer readers (`metrics/<metric>.py`)
+and tiny CPU size (`tests/tiny/<cell>.json`).
 """

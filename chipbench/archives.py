@@ -2,7 +2,7 @@
 
 A deployment opens an archive that already exists, so a run encodes only
 the first time a (configuration, seed) pair meets a checkout: it encodes
-through `GenomicArchive.from_bytes`, writes the result with
+through the corpus module's `build`, writes the result with
 `GenomicArchive.save` under `chipbench/.archives/`, and later runs
 `GenomicArchive.open` it. The file's key hashes the configuration's
 file, the seed and every file under the program's `src/`, so an archive
@@ -13,6 +13,7 @@ from __future__ import annotations
 import hashlib
 import json
 from pathlib import Path
+from typing import Callable
 
 HERE = Path(__file__).resolve().parent
 ARCHIVE_DIR = HERE / ".archives"
@@ -39,9 +40,10 @@ def archive_key(config_name: str, config: dict, seed: int, src: Path) -> str:
 
 
 def open_or_build(config_name: str, config: dict, seed: int, corpus: bytes,
-                  src: Path, directory: Path = ARCHIVE_DIR):
+                  build: Callable, src: Path, directory: Path = ARCHIVE_DIR):
     """(GenomicArchive, built) for this configuration and seed: opened
-    from the cache, or encoded and then saved there."""
+    from the cache, or built by `build(corpus, config, **cache)` and then
+    saved there."""
     from repro.api import GenomicArchive
     path = directory / (f"{config_name}-{int(seed)}-"
                         f"{archive_key(config_name, config, seed, src)}"
@@ -50,9 +52,7 @@ def open_or_build(config_name: str, config: dict, seed: int, corpus: bytes,
                  cache_policy=config.get("cache_policy", "lru"))
     if path.exists():
         return GenomicArchive.open(str(path), **cache), False
-    ga = GenomicArchive.from_bytes(
-        corpus, block_size=int(config["block_size"]), mode=config["mode"],
-        entropy=config["entropy"], **cache)
+    ga = build(corpus, config, **cache)
     directory.mkdir(parents=True, exist_ok=True)
     ga.save(str(path))
     return ga, True

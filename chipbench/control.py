@@ -9,9 +9,10 @@ records for the blocks it decodes (`one_bucket_short`: 7 rounds become
 4, 9 become 8). One round short also breaks the guarantee, but only at
 the ends of the deepest chains, too rarely for a window of point reads
 to meet on every seed. The other faults break the timed path where an
-answer is produced (`altered_answer`: every decoded byte flipped) or lose half of
-the answers (`dropped_half`: the frontend's results, or the stream's
-chunks). Each is planted after warm-up, just before the window.
+answer is produced (`altered_answer`: every decoded byte flipped) or lose
+half of the answers (`dropped_half`: the cell's driver loses them its own
+way, as its `drop_half` says). Each is planted after warm-up, just
+before the window, on every decoder the driver names.
 
     python3 chipbench/control.py --workload <cell> --seconds <s> --seeds 1 2 3 \
         [--faults one_bucket_short] [--program]
@@ -40,8 +41,7 @@ def bucket_below(rounds: int) -> int:
         else 0
 
 
-def one_bucket_short(state) -> None:
-    dec = state.ga.store.decoder
+def _shorten(dec) -> None:
     meta = dec._meta
 
     def short_meta(n_sel, total=None, n_rounds=-1):
@@ -51,8 +51,7 @@ def one_bucket_short(state) -> None:
     dec._meta = short_meta
 
 
-def altered_answer(state) -> None:
-    dec = state.ga.store.decoder
+def _flip(dec) -> None:
     decode = dec.decode_blocks
 
     def flipped(*args, **kwargs):
@@ -61,23 +60,18 @@ def altered_answer(state) -> None:
     dec.decode_blocks = flipped
 
 
+def one_bucket_short(state) -> None:
+    for dec in state.decoders():
+        _shorten(dec)
+
+
+def altered_answer(state) -> None:
+    for dec in state.decoders():
+        _flip(dec)
+
+
 def dropped_half(state) -> None:
-    if hasattr(state, "fe"):
-        take = state.fe.take_results
-
-        def half():
-            return {k: v for k, v in take().items() if k % 2 == 0}
-
-        state.fe.take_results = half
-    else:
-        chunks = state.ex.chunks
-
-        def every_other(addrs):
-            for i, c in enumerate(chunks(addrs)):
-                if i % 2 == 0:
-                    yield c
-
-        state.ex.chunks = every_other
+    state.drop_half()
 
 
 FAULTS = {"one_bucket_short": one_bucket_short,

@@ -1,4 +1,5 @@
-"""Seeded FASTQ corpus and its plain host reference.
+"""The platinum FASTQ corpus: its seeded generator, its plain host
+reference, and the archive the deployment opens over it.
 
 `platinum_fastq` is a vectorized copy of the program's
 `make_fastq("platinum")`: the same distributions (a fragment pool of
@@ -56,6 +57,26 @@ def platinum_fastq(n_reads: int, read_len: int, seed: int) -> bytes:
     out[qual_at + col] = quals
     out[qual_at[:, 0] + read_len] = ord("\n")
     return out.tobytes()
+
+
+def generate(config: dict, seed: int) -> bytes:
+    return platinum_fastq(int(config["n_reads"]), int(config["read_len"]),
+                          seed)
+
+
+def build(corpus: bytes, config: dict, **cache):
+    """The archive a FASTQ deployment opens: encoded with its read index
+    and device name table (`GenomicArchive.from_bytes`)."""
+    from repro.api import GenomicArchive
+    return GenomicArchive.from_bytes(
+        corpus, block_size=int(config["block_size"]), mode=config["mode"],
+        entropy=config["entropy"], **cache)
+
+
+def check(ga, ref: "HostReference") -> None:
+    if ga.n_reads != ref.n_reads:
+        raise RuntimeError(f"archive holds {ga.n_reads} reads, the corpus "
+                           f"{ref.n_reads}")
 
 
 class HostReference:

@@ -2,10 +2,20 @@
 the host reference, and the result line.
 
 Everything particular to a cell comes from files found by the names in
-`BENCHMARK.json`: the configuration (`configs/<config>.json`), the traffic
-mix (`traffic/<mix>.json`) and each per-layer metric's reader
-(`metrics/<metric>.py`, a function `read(readings)` that returns a number
-or None).
+`BENCHMARK.json`, so a deployment is added as new files alone:
+
+* the configuration (`configs/<config>.json`), whose `corpus` names
+* the corpus module (`corpora/<corpus>.py`): `generate(config, seed)`
+  gives the corpus bytes, its class `HostReference(corpus)` the plain
+  reference (it imports nothing of the program), `build(corpus, config,
+  **cache)` the program's archive over the bytes, and `check(ga, ref)`
+  raises where archive and reference disagree;
+* the traffic mix (`traffic/<mix>.json`), whose `driver` names
+* the request driver (`drivers/<driver>.py`, its class `Driver`, see
+  `Driver` below);
+* each per-layer metric's reader (`metrics/<metric>.py`, a function
+  `read(readings)` that returns a number or None);
+* for the CPU tests, the cell's tiny size (`tests/tiny/<cell>.json`).
 """
 from __future__ import annotations
 
@@ -17,26 +27,22 @@ import os
 import shutil
 import sys
 import time
-from concurrent.futures import ThreadPoolExecutor
 from pathlib import Path
 from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
-from chipbench import loadgen, trace as tracing, warmup
+from chipbench import loadgen, trace as tracing
 from chipbench.archives import ARCHIVE_DIR, open_or_build
-from chipbench.corpus import HostReference, platinum_fastq
 from chipbench.peaks import PEAKS, peak
-from chipbench.workcount import DecodeLog, decode_work_bytes
+from chipbench.workcount import DecodeLog
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parent
 SRC = ROOT / "src"
 BENCHMARK = ROOT / "BENCHMARK.json"
 TRACE_DIR = HERE / ".traces"
-DRAIN_S = 60.0             # how long answers due in the window are awaited
 TRACE_S = 10.0             # a traced run profiles the window's last seconds
-KEEP_STREAM_BYTES = 2 << 30  # stream chunks held for the check, at most
 
 
 class NoChip(RuntimeError):
@@ -52,6 +58,7 @@ class Cell:
     chips: int
     end_to_end: List[dict]
     per_layer: List[dict]
+    home: Path                 # where its files are found by name
 
 
 def _applies(metric: dict, cell: str) -> bool:
@@ -69,22 +76,34 @@ def load_cell(name: str, bench_path: Path = BENCHMARK) -> Cell:
     cfg = next(c for c in bench["configs"] if c["name"] == w["config"])
     with open(bench_path.parent / cfg["file"]) as f:
         config = json.load(f)
-    mix = loadgen.load_mix(HERE / "traffic" / f"{w['traffic']}.json")
+    home = bench_path.parent / HERE.name
+    mix = loadgen.load_mix(home / "traffic" / f"{w['traffic']}.json")
     return Cell(name=name, config_name=w["config"], config=config, mix=mix,
                 chips=int(w["chips"]),
                 end_to_end=[m for m in bench["end_to_end"]
                             if _applies(m, name)],
                 per_layer=[m for m in bench["per_layer"]
-                           if _applies(m, name)])
+                           if _applies(m, name)],
+                home=home)
 
 
-def metric_reader(name: str) -> Callable:
-    path = HERE / "metrics" / f"{name}.py"
+def load_module(home: Path, kind: str, name: str):
+    """`<home>/<kind>/<name>.py`, loaded by its path (a metric's name has
+    dots in it)."""
+    path = home / kind / f"{name}.py"
     spec = importlib.util.spec_from_file_location(
-        f"chipbench.metrics.{name.replace('.', '_')}", path)
+        f"chipbench.{kind}.{name.replace('.', '_')}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def metric_reader(name: str, home: Path = HERE) -> Callable:
+    return load_module(home, "metrics", name).read
+
+
+def driver_class(cell: Cell) -> type:
+    return load_module(cell.home, "drivers", cell.mix["driver"]).Driver
 
 
 @dataclasses.dataclass
@@ -126,13 +145,12 @@ class CompileCounter:
 class Readings:
     """What the per-layer readers read: the counters over the window,
     the trace and the decode work over its traced part."""
-    kind: str                      # "open" | "closed"
+    kind: str                      # the driver's loop, "open" | "closed"
     requests: int
     cache_before: dict
     cache_after: dict
-    decoded_blocks: np.ndarray     # distinct blocks of each decode launch
-                                   # (of the traced part)
-    work_bytes: int                # their compressed + decoded bytes
+    work_bytes: int                # compressed + decoded bytes of the
+                                   # blocks the traced part decoded
     reduction: Optional[tracing.Reduction]
     peak_bytes_per_s: float
     latencies_ms: Optional[np.ndarray] = None  # point reads of the window
@@ -141,178 +159,36 @@ class Readings:
         return int(self.cache_after[key]) - int(self.cache_before[key])
 
 
-# ------------------------------------------------------------ point cells
-class PointCell:
-    """Open-loop point reads by read id through `ServingFrontend`."""
+class Driver:
+    """A cell's request driver: the class `Driver` of
+    `drivers/<driver>.py`, named by the traffic file's `driver`. Set-up
+    makes it once the archive is open and calls `warm()`, which runs
+    every shape the window will use. `window(seconds, mark)` drives the
+    program and returns a record; `mark` = (at, fn), where given, asks
+    for fn() once, between requests, `at` seconds into the window (a
+    traced run profiles from there). The check, the result line and the
+    readers take from that record: `check(rec)` {name: (number, limit)},
+    `failed(rec, checks)`, `requests(rec)`, `end_to_end(rec)` {metric:
+    value}, `describe(rec)` and `latencies_ms(rec)`. `drop_half()` makes
+    the program lose half of the window's answers, as a fault of the
+    control. `loop` is "open" where requests arrive on a schedule at the
+    mix's `rate_per_s`, which `sweep.py` sets."""
 
-    tenant = "points"
+    loop = "closed"
 
-    def __init__(self, ga, ref: HostReference, config: dict, mix: dict,
-                 rng: np.random.Generator, log,
-                 warm_rng: np.random.Generator):
-        from repro.serving.frontend import ServingFrontend
+    def __init__(self, ga, ref, config: dict, mix: dict,
+                 rng: np.random.Generator, log: Callable,
+                 warm_rng: np.random.Generator, devices: list):
         self.ga, self.ref, self.config, self.mix = ga, ref, config, mix
         self.rng, self.log, self.warm_rng = rng, log, warm_rng
-        self.fe = ServingFrontend({"corpus": ga},
-                                  max_batch=int(config["max_batch"]))
-        self.fe.register_tenant(self.tenant, "corpus",
-                                max_queue=int(config["max_queue"]),
-                                priority=0)
+        self.devices = devices
 
-    def _serve(self, ids) -> None:
-        for r in ids:
-            self.fe.submit(self.tenant, int(r))
-        self.fe.drain()
-        self.fe.take_results()
+    def decoders(self) -> list:
+        """The decoders whose launches the work count sums."""
+        return [self.ga.store.decoder]
 
-    def warm(self) -> None:
-        reqs = warmup.point_requests(self.ref.starts, self.ga.block_size,
-                                     int(self.config["max_batch"]))
-        for clear, ids in reqs:
-            if clear:
-                self.ga.clear_cache()
-            self._serve(ids)
-        self.ga.clear_cache()
-        self.log(f"warm-up: {len(reqs)} shape batches; "
-                 + self._steady_state())
-
-    def _steady_state(self) -> str:
-        """Bring the block cache to the state the cell's own traffic keeps
-        it in: serve `warm_requests` keys of the mix's distribution, drawn
-        from the seed apart from the window's, unmeasured and at full
-        speed, and report the hit rate of the second half."""
-        n = int(self.mix.get("warm_requests", 0))
-        keys = loadgen.draw_keys(self.mix, self.ref.n_reads, self.warm_rng,
-                                 n)
-        step = int(self.config["max_batch"])
-        half = None
-        for i in range(0, n, step):
-            if half is None and i >= n // 2:
-                half = dict(self.ga.cache_info())
-            self._serve(keys[i:i + step])
-        if half is None:
-            return "no steady-state requests"
-        end = self.ga.cache_info()
-        hits = end["hits"] - half["hits"]
-        looked = hits + end["misses"] - half["misses"]
-        return (f"{n} steady-state requests, hit rate of the second half "
-                f"{100.0 * hits / max(looked, 1):.2f}%, "
-                f"{end['resident']} blocks resident")
-
-    def window(self, seconds: float, mark=None):
-        due = loadgen.arrivals(self.mix, seconds, self.rng)
-        keys = loadgen.draw_keys(self.mix, self.ref.n_reads, self.rng,
-                                 due.size)
-        return loadgen.run_open_loop(self.fe, self.tenant, due, keys,
-                                     seconds, drain_s=DRAIN_S, mark=mark)
-
-    def check(self, rec) -> dict:
-        wrong = missing = 0
-        for key, status, payload in zip(rec.keys.tolist(), rec.status,
-                                        rec.payloads):
-            if status == "missing":
-                missing += 1
-            elif status == "ok" and (payload is None or payload.tobytes()
-                                     != self.ref.record(key)):
-                wrong += 1
-        return {"wrong_answers": (wrong, 0), "missing_answers": (missing, 0)}
-
-    def failed(self, rec, checks: dict) -> int:
-        return (sum(s != "ok" for s in rec.status)
-                + checks["wrong_answers"][0])
-
-    def latencies_ms(self, rec) -> np.ndarray:
-        """Every request of the window, from when it was due to when its
-        bytes were on the host; one never answered, or answered with
-        anything but its bytes, counts as waiting until the drain ended."""
-        done = np.where(np.isnan(rec.done), rec.window_s + DRAIN_S, rec.done)
-        lat = (done - rec.due) * 1e3
-        bad = np.asarray([s != "ok" for s in rec.status])
-        lat[bad] = np.maximum(lat[bad], (rec.window_s + DRAIN_S) * 1e3)
-        return lat
-
-    def end_to_end(self, rec) -> Dict[str, float]:
-        return {"point_read_p50_ms":
-                float(np.percentile(self.latencies_ms(rec), 50))}
-
-    def describe(self, rec) -> str:
-        late = rec.lateness
-        return (f"window: {rec.due.size} requests in {rec.window_s:.1f}s "
-                f"({rec.due.size / rec.window_s:.2f}/s offered), "
-                f"{rec.steps} frontend steps, generator lateness p50 "
-                f"{np.percentile(late, 50) * 1e3:.3f} ms, p95 "
-                f"{np.percentile(late, 95) * 1e3:.3f} ms, max "
-                f"{late.max() * 1e3:.3f} ms")
-
-    def requests(self, rec) -> int:
-        return int(rec.due.size)
-
-
-# ----------------------------------------------------------- stream cells
-class RangeCell:
-    """One closed-loop bulk reader streaming byte ranges under the
-    configuration's device budget."""
-
-    def __init__(self, ga, ref: HostReference, config: dict, mix: dict,
-                 rng: np.random.Generator, log,
-                 warm_rng: np.random.Generator):
-        from repro.api.executors import StreamingExecutor
-        self.ga, self.ref, self.config, self.mix = ga, ref, config, mix
-        self.rng, self.log = rng, log
-        self.ex = StreamingExecutor(
-            ga.store, max_resident_bytes=int(config["max_resident_bytes"]),
-            planner=ga.planner)
-        self.k = self.ex.max_blocks_per_chunk
-        n_chunks = -(-ga.store.decoder.da.n_blocks // self.k)
-        self.start = int(rng.integers(n_chunks)) * self.k * ga.block_size
-
-    def warm(self) -> None:
-        from repro.api import ByteRange
-        dec = self.ga.store.decoder
-        chunks = warmup.stream_chunks(dec.block_rounds, dec.da.n_blocks,
-                                      self.ga.block_size, self.ga.raw_size,
-                                      self.k)
-
-        def one(span) -> None:
-            for _ in self.ex.chunks([ByteRange(*span)]):
-                pass
-
-        # every chunk shape has programs of its own, and the decoder's
-        # sizes follow the archive, so a fresh seed compiles them all:
-        # compile them side by side (the compiler releases the GIL)
-        with ThreadPoolExecutor(max_workers=len(chunks)) as pool:
-            list(pool.map(one, chunks))
-        self.log(f"warm-up: {len(chunks)} distinct chunk shapes of "
-                 f"{self.k} blocks, stream starts at byte {self.start}")
-
-    def window(self, seconds: float, mark=None):
-        return loadgen.run_range_stream(self.ex, self.ga.raw_size,
-                                        self.start, seconds, self.rng,
-                                        KEEP_STREAM_BYTES, mark=mark)
-
-    def check(self, rec) -> dict:
-        wrong = sum(not np.array_equal(chunk,
-                                       self.ref.span(pos, pos + chunk.size))
-                    for pos, chunk in rec.kept)
-        return {"wrong_chunks": (wrong, 0),
-                "short_passes": (rec.short_passes, 0)}
-
-    def failed(self, rec, checks: dict) -> int:
-        return checks["wrong_chunks"][0] + rec.short_passes
-
-    def latencies_ms(self, rec) -> None:
+    def latencies_ms(self, rec) -> Optional[np.ndarray]:
         return None
-
-    def end_to_end(self, rec) -> Dict[str, float]:
-        return {"range_decode_GBps": rec.bytes / rec.seconds / 1e9}
-
-    def describe(self, rec) -> str:
-        return (f"window: {rec.chunks} chunks, {rec.bytes} bytes in "
-                f"{rec.seconds:.3f}s, {rec.passes_ended} passes ended, "
-                f"{len(rec.kept)} chunks kept for the check")
-
-    def requests(self, rec) -> int:
-        return int(rec.chunks)
 
 
 # ------------------------------------------------------------------- run
@@ -365,8 +241,8 @@ class Session:
     log: Callable
     counter: CompileCounter
     ga: object
-    ref: HostReference
-    state: object
+    ref: object
+    state: Driver
     decode_log: DecodeLog
     raw_bytes: int
     resident_bytes: int
@@ -400,21 +276,19 @@ def open_session(cell: Cell, seed: int, hooks: Optional[Hooks] = None,
     mix = {**cell.mix, **hooks.mix_overrides}
     corpus_seq, traffic_seq, warm_seq = seed_sequence(seed).spawn(3)
     t = time.perf_counter()
-    corpus = platinum_fastq(int(config["n_reads"]), int(config["read_len"]),
-                            int(corpus_seq.generate_state(1)[0]))
-    ref = HostReference(corpus)
+    corpora = load_module(cell.home, "corpora", config["corpus"])
+    corpus = corpora.generate(config, int(corpus_seq.generate_state(1)[0]))
+    ref = corpora.HostReference(corpus)
     t_gen = time.perf_counter() - t
     t = time.perf_counter()
-    ga, built = open_or_build(cell.config_name, config, seed, corpus, SRC,
-                              hooks.archive_dir)
+    ga, built = open_or_build(cell.config_name, config, seed, corpus,
+                              corpora.build, SRC, hooks.archive_dir)
     t_arch = time.perf_counter() - t
-    if ga.n_reads != ref.n_reads:
-        raise RuntimeError(f"archive holds {ga.n_reads} reads, the corpus "
-                           f"{ref.n_reads}")
-    decode_log = DecodeLog(ga.store.decoder)
+    corpora.check(ga, ref)
     rng = np.random.default_rng(traffic_seq)
-    state = (PointCell if mix["loop"] == "open" else RangeCell)(
-        ga, ref, config, mix, rng, log, np.random.default_rng(warm_seq))
+    state = driver_class(cell)(ga, ref, config, mix, rng, log,
+                               np.random.default_rng(warm_seq), devices)
+    decode_log = DecodeLog(state.decoders())
     t = time.perf_counter()
     state.warm()
     t_warm = time.perf_counter() - t
@@ -483,7 +357,7 @@ def measure(s: Session, seconds: float, trace: bool) -> dict:
     else:
         rec = state.window(seconds)
     cache1 = dict(ga.cache_info())
-    decoded = s.decode_log.since(started[2] if trace else mark)
+    work_bytes = s.decode_log.work_bytes(started[2] if trace else mark)
     in_window = s.counter.compiles - compiles0
     memory_peak = _peak_bytes(d0)
     s.log(state.describe(rec))
@@ -502,13 +376,12 @@ def measure(s: Session, seconds: float, trace: bool) -> dict:
               f"{sum(map(len, events.ops.values()))} device operations, "
               f"read and reduced in {time.perf_counter() - t:.3f}s")
         readings = Readings(
-            kind=state.mix["loop"], requests=state.requests(rec),
-            cache_before=cache0, cache_after=cache1, decoded_blocks=decoded,
-            work_bytes=decode_work_bytes(ga.store.decoder.archive, decoded),
+            kind=state.loop, requests=state.requests(rec),
+            cache_before=cache0, cache_after=cache1, work_bytes=work_bytes,
             reduction=reduction, peak_bytes_per_s=s.peak["hbm_bytes_per_s"],
             latencies_ms=state.latencies_ms(rec))
         for m in cell.per_layer:
-            value = metric_reader(m["name"])(readings)
+            value = metric_reader(m["name"], cell.home)(readings)
             if value is not None:
                 metrics[m["name"]] = {"value": value, "unit": m["unit"]}
     else:

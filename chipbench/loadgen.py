@@ -1,19 +1,19 @@
 """The one traffic generator: reads a mix's parameters from
-`traffic/<mix>.json` and drives the system with it.
+`traffic/<mix>.json`, and gives the mix's driver (`drivers/<driver>.py`)
+its draws and its loops:
 
-Two loops exist, chosen by the mix's `loop`:
-
-* "open" — independent point readers. Arrivals follow the mix's process
-  (`poisson`, or `on_off` bursts) at `rate_per_s`, keys come from
-  `keys` (`scrambled_zipfian` with `theta`, as YCSB's
-  ScrambledZipfianGenerator, or `uniform`), and every request is timed
-  from when it was due, so a stall of the server delays the requests
-  behind it. Requests are submitted to the program's `ServingFrontend`
-  and answered by its `step()`.
-* "closed" — one bulk reader streams `ByteRange`s through the program's
-  `StreamingExecutor`: from a seed-chosen start, aligned to a whole chunk
-  of blocks, to the end of the archive, then from 0 again, until the
-  window has passed; the window ends with the last whole chunk.
+* an open loop (`run_open_loop`): independent point readers. Arrivals
+  follow the mix's process (`poisson`, or `on_off` bursts) at
+  `rate_per_s`, keys come from `keys` (`scrambled_zipfian` with `theta`,
+  as YCSB's ScrambledZipfianGenerator, or `uniform`), and every request
+  is timed from when it was due, so a stall of the server delays the
+  requests behind it. Requests are submitted to the program's
+  `ServingFrontend` and answered by its `step()`.
+* a closed loop (`run_range_stream`): one bulk reader streams
+  `ByteRange`s through the program's `StreamingExecutor`: from a
+  seed-chosen start, aligned to a whole chunk of blocks, to the end of
+  the archive, then from 0 again, until the window has passed; the
+  window ends with the last whole chunk.
 
 Every draw comes from the run's seed. The host spans
 (`jax.profiler.TraceAnnotation`) are the benchmark's own, around its
@@ -36,8 +36,8 @@ _FNV_PRIME = np.uint64(0x100000001B3)
 def load_mix(path: Path) -> dict:
     with open(path) as f:
         mix = json.load(f)
-    if mix.get("loop") not in ("open", "closed"):
-        raise ValueError(f"{path}: loop must be 'open' or 'closed'")
+    if not isinstance(mix.get("driver"), str):
+        raise ValueError(f"{path}: the mix names no driver")
     return mix
 
 

@@ -29,7 +29,9 @@ for p in (HERE.parent / "src", HERE.parent):
         sys.path.insert(0, str(p))
 
 
-def summarize(rec, checks) -> dict:
+def summarize(rec, checks: dict, failed: int) -> dict:
+    """One rate's row, from an open loop's record (`loadgen.OpenLoopRecord`)
+    and the cell's correctness check of it (`Driver.check`)."""
     lat = (rec.done - rec.due) * 1e3
     n = lat.size
     q = max(1, n // 4)
@@ -45,8 +47,8 @@ def summarize(rec, checks) -> dict:
             "p99_ms": p(lat_ok, 99),
             "p95_first_quarter_ms": p(lat[:q][ok[:q]], 95),
             "p95_last_quarter_ms": p(lat[-q:][ok[-q:]], 95),
-            "wrong": checks["wrong_answers"][0],
-            "missing": checks["missing_answers"][0]}
+            "failed": failed,
+            "checks": {k: v for k, (v, _) in checks.items()}}
 
 
 def sustained(row: dict, base_p50_ms: float) -> bool:
@@ -68,15 +70,15 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--rates", type=float, nargs="+", required=True)
     args = ap.parse_args(argv)
+    from chipbench import harness
+    cell = harness.load_cell(args.workload)
+    if harness.driver_class(cell).loop != "open":
+        print("sweep: the cell's driver has no open loop", file=sys.stderr)
+        return 2
     os.environ["JAX_COMPILATION_CACHE_DIR"] = str(HERE / ".jax_cache")
     from repro.launch.hygiene import apply_process_hygiene, enable_compile_cache
     apply_process_hygiene()
     enable_compile_cache()
-    from chipbench import harness
-    cell = harness.load_cell(args.workload)
-    if cell.mix["loop"] != "open":
-        print("sweep: the cell's traffic is not an open loop", file=sys.stderr)
-        return 2
     try:
         s = harness.open_session(cell, args.seed)
     except harness.NoChip as e:
@@ -87,7 +89,8 @@ def main(argv=None) -> int:
         s.state.mix["rate_per_s"] = rate
         compiles = s.counter.compiles
         rec = s.state.window(args.seconds)
-        row = summarize(rec, s.state.check(rec))
+        checks = s.state.check(rec)
+        row = summarize(rec, checks, s.state.failed(rec, checks))
         row["rate_per_s"] = rate
         row["compiles_in_window"] = s.counter.compiles - compiles
         base = row["p50_ms"] if base is None else base

@@ -1,5 +1,5 @@
-"""Each cell end to end on the CPU at a tiny size, and the entry point's
-refusals."""
+"""Each cell of `BENCHMARK.json` end to end on the CPU at its tiny size,
+and the refusals of the entry point and of the sweep."""
 import json
 import os
 import shutil
@@ -13,7 +13,8 @@ import _tiny
 from chipbench import harness
 
 ROOT = Path(__file__).resolve().parents[2]
-CELLS = ["ra16k.zipf_open", "ra1m.range_stream"]
+CELLS = _tiny.cells()
+POINT = "ra16k.zipf_open"
 
 
 def _names(metrics):
@@ -45,7 +46,7 @@ def test_cell_runs_end_to_end(cell, tmp_path):
 def _run_py(cwd, env_extra=None):
     env = dict(os.environ, JAX_PLATFORMS="cpu", **(env_extra or {}))
     return subprocess.run(
-        [sys.executable, "chipbench/run.py", "--workload", CELLS[0],
+        [sys.executable, "chipbench/run.py", "--workload", POINT,
          "--seed", "1", "--seconds", "1", "--trace", "0"],
         cwd=cwd, env=env, capture_output=True, text=True, timeout=300)
 
@@ -68,9 +69,16 @@ def test_benchmark_alone_exits_nonzero_without_a_result(tmp_path):
 
 def test_point_warmup_leaves_the_cache_in_the_traffics_steady_state(
         tmp_path):
-    s = harness.open_session(harness.load_cell(CELLS[0]), 5,
-                             _tiny.hooks(CELLS[0], tmp_path))
+    s = harness.open_session(harness.load_cell(POINT), 5,
+                             _tiny.hooks(POINT, tmp_path))
     info = s.ga.cache_info()
-    assert info["resident"] == _tiny.POINT["cache_blocks"]
+    assert info["resident"] == _tiny.sizes(POINT)["config"]["cache_blocks"]
     # the traffic's own keys ran through a full cache, hitting and evicting
     assert info["hits"] > 0 and info["evictions"] > 0
+
+
+def test_sweep_refuses_a_cell_whose_driver_has_no_open_loop(capsys):
+    from chipbench import sweep
+    assert sweep.main(["--workload", "ra1m.range_stream", "--seed", "1",
+                       "--seconds", "1", "--rates", "1"]) == 2
+    assert "no open loop" in capsys.readouterr().err

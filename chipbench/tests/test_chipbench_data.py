@@ -1,5 +1,6 @@
 """The corpus generator, the host reference, the archive cache key, the
 traffic generator, the warm-up batches and the file lookup by name."""
+import hashlib
 import json
 import shutil
 from pathlib import Path
@@ -7,10 +8,13 @@ from pathlib import Path
 import numpy as np
 import pytest
 
+import _tiny
 from chipbench import archives, harness, loadgen, warmup
-from chipbench.corpus import HostReference, platinum_fastq
 
 ROOT = Path(__file__).resolve().parents[2]
+PLATINUM = harness.load_module(harness.HERE, "corpora", "platinum_fastq")
+platinum_fastq = PLATINUM.platinum_fastq
+HostReference = PLATINUM.HostReference
 
 
 def test_generator_is_a_function_of_the_seed():
@@ -63,7 +67,24 @@ def test_archive_key_follows_every_source_file(tmp_path):
     assert archives.archive_key("c", cfg, 1, src) == key
 
 
-@pytest.mark.parametrize("cell", ["ra16k.zipf_open", "ra1m.range_stream"])
+@pytest.mark.parametrize("n_reads,seed,digest", [
+    (500, 7,
+     "aad88162e4290d4716838e07fb5f28f8475a9fb0a1129885fb0eac6dee323044"),
+    (500, 2**31 + 5,
+     "d4bfef36d022e09d3140704a13ebe700de5611c0467b21de6e8831cd7dd3fcae"),
+    (4321, 7,
+     "a896182c89ebbf651444618271d3096db10a363f5be3fce7a32b5a29324d8fc4"),
+    (4321, 2**31 + 5,
+     "f541a0eb01fe8fc6bcefca119f135c9608d65b7da35090bd128d577c5ae3a3e2")])
+def test_platinum_corpus_bytes_are_pinned(n_reads, seed, digest):
+    """The corpus module's `generate` gives, for each seed and size, the
+    bytes the generator gave before it moved into the module."""
+    config = {"n_reads": n_reads, "read_len": 100}
+    assert hashlib.sha256(PLATINUM.generate(config, seed)).hexdigest() \
+        == digest
+
+
+@pytest.mark.parametrize("cell", _tiny.cells())
 def test_cell_files_are_found_by_name(cell):
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
     w = next(w for w in bench["workloads"] if w["name"] == cell)
@@ -74,6 +95,10 @@ def test_cell_files_are_found_by_name(cell):
         .read_text())
     assert c.mix == json.loads(
         (ROOT / "chipbench" / "traffic" / f"{w['traffic']}.json").read_text())
+    assert callable(harness.load_module(c.home, "corpora",
+                                        c.config["corpus"]).generate)
+    assert issubclass(harness.driver_class(c), harness.Driver)
+    assert set(_tiny.sizes(cell)) >= {"config", "traffic"}
     assert {m["name"] for m in c.end_to_end} >= {"setup_s"}
     assert c.per_layer
     for m in c.per_layer:

@@ -5,7 +5,7 @@ import pytest
 import _tiny
 from chipbench import control
 
-CASES = [(cell, fault) for cell in ("ra16k.zipf_open", "ra1m.range_stream")
+CASES = [(cell, fault) for cell in _tiny.cells()
          for fault in sorted(control.FAULTS)]
 
 

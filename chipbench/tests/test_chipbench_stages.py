@@ -7,8 +7,10 @@ import time
 import numpy as np
 import pytest
 
-from chipbench import stages, trace as tracing, workcount
-from chipbench.corpus import platinum_fastq
+from chipbench import harness, stages, trace as tracing, workcount
+
+platinum_fastq = harness.load_module(harness.HERE, "corpora",
+                                     "platinum_fastq").platinum_fastq
 
 
 # ------------------------------------------------------------ synthetic
@@ -224,7 +226,7 @@ def test_decode_info_counts_the_blocks_the_log_records(archive, request):
     of several depth buckets launches once per bucket of a call."""
     ga = request.getfixturevalue(archive)
     dec = ga.store.decoder
-    log = workcount.DecodeLog(dec)
+    log = workcount.DecodeLog([dec])
     try:
         ga.clear_cache()
         before, mark = dec.decode_info(), log.mark()
@@ -232,7 +234,7 @@ def test_decode_info_counts_the_blocks_the_log_records(archive, request):
         for _ in range(6):
             ga.store.fetch_reads(rng.integers(ga.n_reads, size=9))
         after = dec.decode_info()
-        got = log.since(mark)
+        (got,) = log.since(mark)
     finally:
         del dec.decode_blocks            # back to the class's method
     delta = {k: after[k] - before[k] for k in after}

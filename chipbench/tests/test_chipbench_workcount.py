@@ -5,7 +5,9 @@ import numpy as np
 import pytest
 
 from chipbench import harness, peaks, workcount
-from chipbench.corpus import platinum_fastq
+
+platinum_fastq = harness.load_module(harness.HERE, "corpora",
+                                     "platinum_fastq").platinum_fastq
 
 
 @pytest.fixture(scope="module")
@@ -29,21 +31,22 @@ def test_block_work_reads_word_extents_not_shapes(ga):
 
 def test_pad_rows_add_no_work(ga):
     dec = ga.store.decoder
-    log = workcount.DecodeLog(dec)
+    log = workcount.DecodeLog([dec])
     try:
         mark = log.mark()
         dec.decode_blocks(np.asarray([3, 9, 9, 9], np.int32))  # pow2 pad
-        got = log.since(mark)
+        (got,) = log.since(mark)
     finally:
         del dec.decode_blocks            # back to the class's method
     assert sorted(got.tolist()) == [3, 9]
     assert workcount.decode_work_bytes(dec.archive, got) == int(
-        workcount.block_work_bytes(dec.archive)[[3, 9]].sum())
+        workcount.block_work_bytes(dec.archive)[[3, 9]].sum()) \
+        == log.work_bytes(mark)
 
 
 def test_cache_hits_add_no_work(ga):
     dec = ga.store.decoder
-    log = workcount.DecodeLog(dec)
+    log = workcount.DecodeLog([dec])
     try:
         ga.clear_cache()
         ids = np.arange(0, 40, 7)
@@ -51,7 +54,7 @@ def test_cache_hits_add_no_work(ga):
         first = log.mark()
         assert first > 0
         ga.store.fetch_reads(ids)            # every block is a hit now
-        assert log.since(first).size == 0
+        assert log.work_bytes(first) == 0
     finally:
         del dec.decode_blocks
 

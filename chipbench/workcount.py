@@ -26,7 +26,7 @@ def block_work_bytes(archive) -> np.ndarray:
 def decode_work_bytes(archive, decoded_blocks) -> int:
     """Bytes moved by decoding each block of `decoded_blocks` once; ids
     repeated as launch padding count once per launch, so pass each
-    launch's distinct ids (`DecodeLog.blocks`)."""
+    launch's distinct ids (`DecodeLog.since`)."""
     ids = np.asarray(decoded_blocks, np.int64).reshape(-1)
     return int(block_work_bytes(archive)[ids].sum())
 
@@ -41,25 +41,39 @@ def roofline_share_pct(work_bytes: int, device_seconds: float,
 
 
 class DecodeLog:
-    """Records the distinct blocks of every decode launch the program
-    makes through `decoder.decode_blocks` (cache miss decodes and stream
-    chunks both go through it). Installed on the decoder instance from the
-    benchmark's side; the program is unchanged."""
+    """Records the distinct blocks of every decode launch that each of
+    `decoders` makes through its `decode_blocks` (cache miss decodes and
+    stream chunks both go through it). Installed on the decoder instances
+    from the benchmark's side; the program is unchanged."""
 
-    def __init__(self, decoder):
-        self.blocks = []
-        self._decode = decoder.decode_blocks
+    def __init__(self, decoders):
+        self.decoders = list(decoders)
+        self.launches = []         # (position in `decoders`, distinct ids)
+        for i, dec in enumerate(self.decoders):
+            self._install(i, dec)
+
+    def _install(self, i: int, decoder) -> None:
+        decode = decoder.decode_blocks
 
         def decode_blocks(sel, *args, **kwargs):
-            self.blocks.append(np.unique(np.asarray(sel, np.int64)))
-            return self._decode(sel, *args, **kwargs)
+            self.launches.append((i, np.unique(np.asarray(sel, np.int64))))
+            return decode(sel, *args, **kwargs)
 
         decoder.decode_blocks = decode_blocks
 
     def mark(self) -> int:
-        return len(self.blocks)
+        return len(self.launches)
 
-    def since(self, mark: int) -> np.ndarray:
-        """Distinct-per-launch block ids decoded since `mark`."""
-        got = self.blocks[mark:]
-        return (np.concatenate(got) if got else np.zeros(0, np.int64))
+    def since(self, mark: int) -> list:
+        """For each decoder, the distinct-per-launch block ids it decoded
+        since `mark`."""
+        got = [[] for _ in self.decoders]
+        for i, ids in self.launches[mark:]:
+            got[i].append(ids)
+        return [np.concatenate(g) if g else np.zeros(0, np.int64)
+                for g in got]
+
+    def work_bytes(self, mark: int) -> int:
+        """Bytes moved by every decoder's launches since `mark`."""
+        return sum(decode_work_bytes(dec.archive, ids)
+                   for dec, ids in zip(self.decoders, self.since(mark)))
